@@ -122,6 +122,12 @@ def sharded_study(trials: int = 15, wave: int = 8) -> None:
     import jax
 
     if jax.device_count() < 4:
+        if jax.default_backend() != "cpu":
+            # the chip belongs to this process: a child could not reach it
+            emit("sharded_backbone_skipped", 0.0,
+                 f"{jax.device_count()} {jax.default_backend()} device(s); "
+                 f"the study needs 4")
+            return
         _respawn_sharded_study(trials, wave)
         return
     from repro.nn.layers import set_flash_attention
@@ -132,13 +138,13 @@ def sharded_study(trials: int = 15, wave: int = 8) -> None:
     # regime the sharding is for, while still loading in seconds on CPU
     fam = dataclasses.replace(
         FAMILIES["sd3"],
-        toy=dataclasses.replace(FAMILIES["sd3"].toy, d_model=256, n_layers=6,
+        dit=dataclasses.replace(FAMILIES["sd3"].dit, d_model=256, n_layers=6,
                                 n_heads=8, d_ff=1024, latent_size=32))
     backbone = DiffusionBackbone(fam)
     ks = (1, 2, 4)
     prev_flash = set_flash_attention(False)
     try:
-        arms = {k: _ShardArm(k, wave, backbone, fam.toy) for k in ks}
+        arms = {k: _ShardArm(k, wave, backbone, fam.dit) for k in ks}
         for _ in range(trials):
             for k in ks:
                 arms[k].run_trial()
@@ -171,10 +177,12 @@ def sharded_study(trials: int = 15, wave: int = 8) -> None:
 
 
 def _respawn_sharded_study(trials: int, wave: int) -> None:
-    """Too few local devices: rerun this study in a child with 8 forced
-    virtual host devices (results land in the same JSON/CSV stream)."""
+    """Too few local CPU devices: rerun this study in a CPU child with 8
+    forced virtual host devices (results land in the same JSON/CSV
+    stream)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(root, "src")
                + os.pathsep + os.environ.get("PYTHONPATH", ""))

@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import tempfile
 import time
 from typing import Any, Dict, List, Optional
 
@@ -264,7 +263,7 @@ def recovery_parity(steps: int = 5) -> Dict[str, Any]:
 
 
 def run(smoke: bool = False) -> Dict[str, Any]:
-    from repro.core import processes_available
+    from repro.core import processes_available, use_compile_cache
 
     if not processes_available():
         result: Dict[str, Any] = {"skipped": True,
@@ -272,15 +271,8 @@ def run(smoke: bool = False) -> Dict[str, Any]:
         emit("proc_chaos_skipped", 1.0, "sandboxed runner")
     else:
         # one shared on-disk XLA cache for every arm AND every respawned
-        # worker (children inherit the env; the supervisor's own cache
-        # dir is only a fallback)
-        os.environ.setdefault(
-            "JAX_COMPILATION_CACHE_DIR",
-            tempfile.mkdtemp(prefix="repro-bench-proc-xla-"))
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+        # worker (the supervisor hands workers the same directory)
+        use_compile_cache()
         result = {
             "trace": trace_study(smoke=smoke),
             "recovery": recovery_parity(steps=3 if smoke else 5),

@@ -34,6 +34,7 @@ from benchmarks import (
     bench_testbed,
     roofline,
 )
+from repro.core.compile_cache import use_compile_cache
 
 ALL = [
     ("fig3_scaling", bench_scaling),
@@ -65,6 +66,7 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod in ALL:

@@ -8,9 +8,11 @@ model-state table keeps L_load at zero for warm replicas.
 Run:  PYTHONPATH=src python examples/multi_lora_sharing.py
 """
 
-from repro.core import ServingSystem
+from repro.core import ServingSystem, use_compile_cache
 from repro.diffusion import make_basic_workflow, make_lora_workflow
 from repro.sim import generate_trace
+
+use_compile_cache()
 
 system = ServingSystem(n_executors=4, admission_enabled=False)
 wfs = {}

@@ -13,9 +13,10 @@ import os
 
 import numpy as np
 
-from repro.core import LocalBackend, ServingSystem
+from repro.core import LocalBackend, ServingSystem, use_compile_cache
 from repro.diffusion import make_basic_workflow
 
+use_compile_cache()
 system = ServingSystem(n_executors=2, backend=LocalBackend())
 workflow = make_basic_workflow("sd3")
 system.register(workflow)

@@ -10,7 +10,7 @@ Run:  PYTHONPATH=src python examples/serve_cluster.py [--rate 1.0]
 
 import argparse
 
-from repro.core import ProfileStore, ServingSystem
+from repro.core import ProfileStore, ServingSystem, use_compile_cache
 from repro.core.profiles import GPU_H800
 from repro.diffusion import table2_setting
 from repro.sim import MonolithicSystem, WorkflowSpec, generate_trace
@@ -21,6 +21,7 @@ ap.add_argument("--gpus", type=int, default=16)
 ap.add_argument("--duration", type=float, default=240.0)
 ap.add_argument("--cv", type=float, default=2.0)
 args = ap.parse_args()
+use_compile_cache()
 
 wfs = table2_setting("s6")
 trace = generate_trace(list(wfs), rate=args.rate, duration=args.duration,

@@ -21,7 +21,7 @@ for the proc trace).
 
 import argparse
 
-from repro.core import ServingSystem
+from repro.core import ServingSystem, use_compile_cache
 from repro.core.telemetry import configure, validate_chrome_trace
 
 
@@ -68,6 +68,7 @@ def main() -> None:
                          "across pids)")
     ap.add_argument("--out", default="trace.json")
     args = ap.parse_args()
+    use_compile_cache()
     configure(True)
     if args.proc:
         run_proc(args.out)

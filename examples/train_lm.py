@@ -9,6 +9,7 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--steps 300]
 
 import argparse
 
+from repro.core.compile_cache import use_compile_cache
 from repro.data import DataConfig
 from repro.models.base import ArchConfig
 from repro.train import TrainConfig, train
@@ -19,6 +20,7 @@ ap.add_argument("--batch", type=int, default=4)
 ap.add_argument("--seq", type=int, default=256)
 ap.add_argument("--checkpoint-dir", default="/tmp/repro_lm_ckpt")
 args = ap.parse_args()
+use_compile_cache()
 
 cfg = ArchConfig(
     name="lm-100m", arch_type="dense",

@@ -10,6 +10,7 @@ Public surface:
 
 from repro.core.admission import AdmissionController, critical_path_seconds
 from repro.core.autoscaler import Autoscaler, AutoscalerConfig, ScaleAction
+from repro.core.compile_cache import compile_cache_dir, use_compile_cache
 from repro.core.compiler import CompiledGraph, CompileError, GraphCompiler, Pass
 from repro.core.datastore import DataEngine, FetchFuture
 from repro.core.executor import Executor, LocalBackend, OutOfMemory, ShardedBackend
@@ -32,7 +33,14 @@ from repro.core.passes import (
     default_passes,
     segment_fusion_enabled,
 )
-from repro.core.profiles import GPU_H800, TPU_V5E, HardwareSpec, LatencyProfile, ProfileStore
+from repro.core.profiles import (
+    GPU_H800,
+    TPU_V5E,
+    HardwareSpec,
+    LatencyProfile,
+    ProfileStore,
+    hardware_for,
+)
 from repro.core.registry import ServingSystem, WorkflowRegistry
 from repro.core.runtime import Coordinator, Request, RequestNode
 from repro.core.scheduler import ScheduledBatch, Scheduler

@@ -497,13 +497,13 @@ class LocalBackend:
     def _block(out: Any) -> None:
         """Wait for async-dispatched device work: the measured duration
         feeds the coordinator's event timeline, so it must cover the real
-        compute, not just the host-side dispatch."""
-        try:
-            import jax
+        compute, not just the host-side dispatch.  Only array leaves are
+        waited on (plain python payloads need no sync); a device error
+        (out of memory, a failed kernel) propagates to the caller."""
+        import jax
 
-            jax.block_until_ready(out)
-        except Exception:
-            pass  # non-jax payloads (plain python values) need no sync
+        jax.block_until_ready(
+            [x for x in jax.tree.leaves(out) if isinstance(x, jax.Array)])
 
     def execute(self, model: Model, **kwargs: Any) -> Tuple[Dict[str, Any], float]:
         self._maybe_inject_fault()

@@ -19,7 +19,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.admission import AdmissionController
 from repro.core.executor import Executor
-from repro.core.profiles import GPU_H800, HardwareSpec, ProfileStore, node_infer_time
+from repro.core.profiles import GPU_H800, HardwareSpec, node_infer_time
 from repro.core.registry import ServingSystem
 from repro.core.workflow import WorkflowTemplate
 

@@ -32,7 +32,8 @@ class HardwareSpec:
     patch_swap_time: float = 0.05  # s to hot-patch adapter weights in HBM
 
 
-# TPU v5e — the target chip for the roofline analysis (system prompt consts).
+# TPU v5e — published peaks (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops=197e12,
@@ -58,6 +59,26 @@ GPU_H800 = HardwareSpec(
     dispatch_overhead=100e-6,
     transfer_latency=10e-6,
 )
+
+
+# The executable plane's hardware, keyed by the ``device_kind`` JAX
+# reports.  A host CPU (tests, development runs) has no device cost of its
+# own: it is priced on the modelled testbed, like the sim plane.
+DEVICE_HARDWARE: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": GPU_H800,
+}
+
+
+def hardware_for(device: Any) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a JAX device; an unknown kind is an
+    error, never a default."""
+    kind = device.device_kind
+    if kind not in DEVICE_HARDWARE:
+        raise ValueError(
+            f"no HardwareSpec for device_kind {kind!r} "
+            f"(known: {', '.join(sorted(DEVICE_HARDWARE))})")
+    return DEVICE_HARDWARE[kind]
 
 
 # Roofline factors for quantized forwards on a QUANTIZABLE model's cost
@@ -241,7 +262,7 @@ def node_infer_time(profiles: "ProfileStore", node: Any,
 class ProfileStore:
     """Registry of latency profiles keyed by model_id."""
 
-    def __init__(self, hw: HardwareSpec = GPU_H800) -> None:
+    def __init__(self, hw: HardwareSpec) -> None:
         self.hw = hw
         self._profiles: Dict[str, LatencyProfile] = {}
         self._overrides: Dict[str, LatencyProfile] = {}

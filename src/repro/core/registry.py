@@ -15,7 +15,7 @@ from repro.core.compiler import CompiledGraph, GraphCompiler, Pass
 from repro.core.executor import RESERVE, Executor, LocalBackend
 from repro.core.faults import FaultPlane, RetryPolicy
 from repro.core.passes import default_passes
-from repro.core.profiles import GPU_H800, HardwareSpec, ProfileStore
+from repro.core.profiles import GPU_H800, HardwareSpec, ProfileStore, hardware_for
 from repro.core.runtime import Coordinator, Request
 from repro.core.scheduler import Scheduler
 from repro.core.workflow import WorkflowTemplate, freeze_bindings
@@ -51,7 +51,7 @@ class ServingSystem:
     def __init__(
         self,
         n_executors: int = 8,
-        hw: HardwareSpec = GPU_H800,
+        hw: Optional[HardwareSpec] = None,
         scheduler: Optional[Scheduler] = None,
         admission_enabled: bool = False,
         extra_passes: Optional[Sequence[Pass]] = None,
@@ -67,7 +67,12 @@ class ServingSystem:
         metrics: Any = None,
         overlap: Optional[bool] = None,
     ) -> None:
-        """``autoscaler`` enables per-model elastic scaling: pass ``True``
+        """``hw`` prices the profiles the scheduler decides on.  By
+        default the sim plane (no backend) models the paper's H800
+        testbed, and the executable plane takes the spec of the device it
+        runs on (:func:`~repro.core.profiles.hardware_for`).
+
+        ``autoscaler`` enables per-model elastic scaling: pass ``True``
         for the default policy, an :class:`AutoscalerConfig`, or a built
         :class:`Autoscaler`.  ``reserve_executors`` adds that many cold
         standby devices the autoscaler may bring into service (they are
@@ -94,6 +99,13 @@ class ServingSystem:
             from repro.core.supervisor import ProcBackend
 
             backend = ProcBackend()
+        if hw is None:
+            if backend is None:
+                hw = GPU_H800
+            else:
+                import jax
+
+                hw = hardware_for(jax.devices()[0])
         self.profiles = ProfileStore(hw)
         passes = default_passes()
         if extra_passes:

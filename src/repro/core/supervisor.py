@@ -36,9 +36,13 @@ boundary.  Dead processes are respawned through the warm-pool path with
 the measured restart wall seconds charged to the executor's revive
 delay.
 
-Restarted workers inherit a shared on-disk JAX compilation cache (one
-temp dir per supervisor), so recovery re-pays weight initialization but
-not XLA compilation — mirroring how real fleets restart workers.
+Workers share the persistent JAX compilation cache of
+:mod:`repro.core.compile_cache`, so recovery re-pays weight
+initialization but not XLA compilation — mirroring how real fleets
+restart workers.
+
+The process plane is CPU-only: a chip belongs to one process, and the
+parent that drives the workers has already initialized JAX.
 """
 
 from __future__ import annotations
@@ -46,11 +50,11 @@ from __future__ import annotations
 import dataclasses
 import os
 import socket
-import tempfile
 import time as _time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.compile_cache import compile_cache_dir, use_compile_cache
 from repro.core.executor import LocalBackend
 from repro.core.transport import (
     ChecksumError,
@@ -158,14 +162,9 @@ def _worker_main(host: str, port: int, worker_id: int, hb_interval: float,
     """Worker process entry point (spawn target — must be importable)."""
     import threading
 
-    if jax_cache_dir:
-        # shared persistent XLA cache: a restarted worker re-pays weight
-        # init, not compilation (set before jax ever imports)
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", jax_cache_dir)
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-        os.environ.setdefault(
-            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+    # shared persistent XLA cache: a restarted worker re-pays weight
+    # init, not compilation
+    use_compile_cache(jax_cache_dir)
     sock = socket.create_connection((host, port))
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     wlock = threading.Lock()
@@ -343,7 +342,7 @@ class Supervisor:
         self.faults = faults
         self.workers: Dict[int, WorkerHandle] = {}
         self._listener: Optional[socket.socket] = None
-        self._jax_cache_dir = tempfile.mkdtemp(prefix="repro-proc-xla-")
+        self.jax_cache_dir = compile_cache_dir()
         self.n_spawns = 0
         self.n_kills = 0
         # byte counters of channels already torn down (respawn/shutdown)
@@ -372,7 +371,7 @@ class Supervisor:
         h.proc = ctx.Process(
             target=_worker_main,
             args=(host, port, executor_id, self.config.hb_interval,
-                  self.config.staging_entries, self._jax_cache_dir),
+                  self.config.staging_entries, self.jax_cache_dir),
             daemon=True,
         )
         h.proc.start()
@@ -449,6 +448,13 @@ class ProcBackend(LocalBackend):
     is_proc_plane = True
 
     def __init__(self, config: Optional[ProcConfig] = None) -> None:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "the process plane is CPU-only: this process holds the TPU, "
+                "so spawned workers could not reach it; serve on the chip "
+                "with LocalBackend or ShardedBackend")
         super().__init__()
         self.config = config or ProcConfig.from_env()
         self.supervisor = Supervisor(self.config)

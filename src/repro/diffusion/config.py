@@ -1,12 +1,15 @@
 """Diffusion model family configs (§7.1, Table 2).
 
-Each family carries two scales:
+Each family carries:
 
 * ``real-scale`` statistics — parameter counts / token geometry of the
   published checkpoints, used by the analytic latency profiles, the
   monolithic baselines, and the roofline analysis;
-* a ``toy`` executable configuration — the same architecture at CPU-
-  friendly size, used by the executable plane and the correctness tests.
+* ``dit`` — the backbone configuration the executable plane runs: the
+  CPU-sized toy by default, used by the correctness tests;
+* ``published`` — the backbone geometry of the published checkpoint in
+  bf16, which :meth:`DiffusionFamily.at_published_width` swaps in for
+  ``dit`` to serve the family at its real width.
 """
 
 from __future__ import annotations
@@ -60,7 +63,15 @@ class DiffusionFamily:
     text_tokens: int
     d_model_real: int
     n_layers_real: int
-    toy: DiTConfig = None         # executable config
+    dit: DiTConfig = None         # executable backbone config
+    published: Optional[DiTConfig] = None   # published backbone geometry
+
+    def at_published_width(self) -> "DiffusionFamily":
+        """This family with its published backbone geometry as the
+        executable config (same name, so workflows and model ids match)."""
+        if self.published is None:
+            raise ValueError(f"{self.name}: no published backbone geometry")
+        return dataclasses.replace(self, dit=self.published)
 
     @property
     def cfg_factor(self) -> float:
@@ -114,6 +125,25 @@ _TOY = DiTConfig(
     latent_size=16, latent_channels=4, patch=2, text_tokens=8,
 )
 
+# Published backbone geometries in bf16: 1024px images (128x128x16
+# latents, patch 2 -> 4096 image tokens).  The two-stream MMDiT block
+# slightly over-parameterizes Flux's mixed joint/single-stream stack.
+SD3_DIT = DiTConfig(
+    d_model=1536, n_layers=24, n_heads=24, d_ff=6144, text_dim=4096,
+    latent_size=128, latent_channels=16, patch=2, text_tokens=333,
+    dtype=jnp.bfloat16,
+)
+SD35_LARGE_DIT = DiTConfig(
+    d_model=2432, n_layers=38, n_heads=38, d_ff=9728, text_dim=4096,
+    latent_size=128, latent_channels=16, patch=2, text_tokens=333,
+    dtype=jnp.bfloat16,
+)
+FLUX_DIT = DiTConfig(
+    d_model=3072, n_layers=57, n_heads=24, d_ff=12288, text_dim=4096,
+    latent_size=128, latent_channels=16, patch=2, text_tokens=512,
+    dtype=jnp.bfloat16,
+)
+
 SD3 = DiffusionFamily(
     name="sd3",
     backbone_params=2.0e9,
@@ -126,7 +156,8 @@ SD3 = DiffusionFamily(
     text_tokens=333,
     d_model_real=1536,
     n_layers_real=24,
-    toy=_TOY,
+    dit=_TOY,
+    published=SD3_DIT,
 )
 
 SD35_LARGE = DiffusionFamily(
@@ -141,7 +172,8 @@ SD35_LARGE = DiffusionFamily(
     text_tokens=333,
     d_model_real=2432,
     n_layers_real=38,
-    toy=_TOY,
+    dit=_TOY,
+    published=SD35_LARGE_DIT,
 )
 
 FLUX_DEV = DiffusionFamily(
@@ -156,7 +188,8 @@ FLUX_DEV = DiffusionFamily(
     text_tokens=512,
     d_model_real=3072,
     n_layers_real=57,
-    toy=_TOY,
+    dit=_TOY,
+    published=FLUX_DIT,
 )
 
 FLUX_SCHNELL = DiffusionFamily(
@@ -171,7 +204,8 @@ FLUX_SCHNELL = DiffusionFamily(
     text_tokens=512,
     d_model_real=3072,
     n_layers_real=57,
-    toy=_TOY,
+    dit=_TOY,
+    published=FLUX_DIT,
 )
 
 FAMILIES = {f.name: f for f in (SD3, SD35_LARGE, FLUX_DEV, FLUX_SCHNELL)}
@@ -190,6 +224,6 @@ SDXL = DiffusionFamily(
     text_tokens=77,
     d_model_real=1280,
     n_layers_real=70,
-    toy=_TOY,
+    dit=_TOY,
 )
 FAMILIES["sdxl"] = SDXL

@@ -191,8 +191,10 @@ def unpatchify(tokens: jax.Array, patch: int, size: int, channels: int) -> jax.A
 
 
 def _embed_inputs(params: Params, cfg: DiTConfig, latents, t, text_emb):
-    x = patchify(latents, cfg.patch) @ params["patch_embed"]
-    c = text_emb @ params["text_proj"]
+    # token streams run in the param dtype whatever the sampler state and
+    # the text encoder emit (f32 latents into a bf16 backbone)
+    x = patchify(latents, cfg.patch).astype(cfg.dtype) @ params["patch_embed"]
+    c = text_emb.astype(cfg.dtype) @ params["text_proj"]
     t_emb = timestep_embedding(t, 256)
     t_emb = jax.nn.silu(t_emb @ params["t_mlp1"]) @ params["t_mlp2"]
     return x, c, t_emb
@@ -277,15 +279,12 @@ def _mmdit_block_seq(
     q = jnp.concatenate([qt, qi], axis=1)          # [B, Tc + Ti/k, H, hd]
     k = jnp.concatenate([kt, ki], axis=1)          # [B, Tc + Ti,   H, hd]
     v = jnp.concatenate([vt, vi], axis=1)
-    if flash_attention_enabled():
-        # the Pallas kernel's padding-guarded k-sweep handles the
-        # rectangular local-q x global-kv shape natively, so the sharded
-        # path keeps the same flash hot path as the unsharded block
-        from repro.kernels.flash_attention.ops import mha
+    # the Pallas kernel's padding-guarded k-sweep handles the rectangular
+    # local-q x global-kv shape natively, so the sharded path keeps the
+    # same attention route as the unsharded block
+    from repro.kernels.flash_attention.ops import mha
 
-        out = mha(q, k, v, causal=False)
-    else:
-        out = gqa_attention(q, k, v, causal=False)
+    out = mha(q, k, v, causal=False, use_kernel=flash_attention_enabled())
     tc = c.shape[1]
     out_t, out_i = out[:, :tc], out[:, tc:]
     x = _stream_post(p["img"], x, out_i, mods_i, n_heads)
@@ -321,7 +320,7 @@ def mmdit_apply_seq_sharded(
     if control_residuals is None:
         b = latents.shape[0]
         control_residuals = jnp.zeros(
-            (cfg.n_layers, b, cfg.image_tokens, cfg.d_model), latents.dtype)
+            (cfg.n_layers, b, cfg.image_tokens, cfg.d_model), cfg.dtype)
 
     def shard_fn(params, lat, t, emb, res):
         # same embedding as the unsharded forward; patchify sees only this

@@ -2,8 +2,11 @@
 
 Every component of a T2I workflow is a :class:`~repro.core.model.Model`
 subclass whose ``cost()`` carries the real-scale statistics (for profiles,
-baselines, roofline) and whose ``load()/execute()`` run the *toy-scale*
-JAX implementation (for the executable plane).  One code path, two scales.
+baselines, roofline) and whose ``load()/execute()`` run the JAX
+implementation at the family's executable ``dit`` config (for the
+executable plane): the CPU-sized toy by default, the published backbone
+width under ``family.at_published_width()``.  The text encoder and VAE
+are stand-ins at the widths the backbone's interface needs.
 
 Workflow builders (Table 2's S1-S6) live in
 :mod:`repro.diffusion.workflows`; ``repro.diffusion.serving`` re-exports
@@ -155,7 +158,7 @@ class LatentsGenerator(Model):
         self.add_output("latents", TensorType())
 
     def execute(self, model_components: Dict[str, Any], **kw: Any) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         key = jax.random.PRNGKey(int(kw["seed"]))
         lat = jax.random.normal(
             key, (1, cfg.latent_size, cfg.latent_size, cfg.latent_channels)
@@ -165,7 +168,7 @@ class LatentsGenerator(Model):
     def execute_batch(
         self, model_components: Dict[str, Any], batch_kwargs: List[Dict[str, Any]]
     ) -> List[Dict[str, Any]]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         shape = (1, cfg.latent_size, cfg.latent_size, cfg.latent_channels)
         keys = jnp.stack(
             [jax.random.PRNGKey(int(kw["seed"])) for kw in batch_kwargs])
@@ -188,7 +191,7 @@ class TextEncoder(Model):
         self.add_output("prompt_embeds", TensorType())
 
     def load(self, device: Any = None) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         params = quantize_text_params(init_text_encoder(
             jax.random.PRNGKey(stable_hash(self.model_id) % 2**31),
             _TOY_VOCAB, cfg.text_dim, n_layers=2, n_heads=4,
@@ -229,7 +232,7 @@ class TextEncoder(Model):
         stack = _cached_lora_stack(
             model_components, order, adapters, cache_key="_ml_text_stacks",
             field="text_lora", build=stack_text_loras)
-        cfg = self.family.toy
+        cfg = self.family.dit
         ids = tokenize_batch([kw["prompt"] for kw in batch_kwargs],
                              _TOY_VOCAB, cfg.text_tokens)
         idx = jnp.asarray(np.asarray(per_req, np.int32))
@@ -237,7 +240,7 @@ class TextEncoder(Model):
         return [{"prompt_embeds": emb[i:i + 1]} for i in range(len(batch_kwargs))]
 
     def execute(self, model_components: Dict[str, Any], **kw: Any) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         ids = tokenize(kw["prompt"], _TOY_VOCAB, cfg.text_tokens)
         emb = model_components["apply"](model_components["params"], ids)
         return {"prompt_embeds": emb}
@@ -245,7 +248,7 @@ class TextEncoder(Model):
     def execute_batch(
         self, model_components: Dict[str, Any], batch_kwargs: List[Dict[str, Any]]
     ) -> List[Dict[str, Any]]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         ids = tokenize_batch([kw["prompt"] for kw in batch_kwargs],
                              _TOY_VOCAB, cfg.text_tokens)
         emb = model_components["apply"](model_components["params"], ids)
@@ -294,7 +297,7 @@ class DiffusionBackbone(Model):
         self.add_output("velocity", TensorType())
 
     def load(self, device: Any = None) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         params = quantize_mmdit_params(init_mmdit(
             jax.random.PRNGKey(stable_hash(self.model_id) % 2**31), cfg))
         apply = jax.jit(
@@ -371,7 +374,7 @@ class DiffusionBackbone(Model):
         if res is None:
             res = jnp.zeros(
                 (cfg.n_layers, lat.shape[0], cfg.image_tokens, cfg.d_model),
-                lat.dtype,
+                cfg.dtype,
             )
         return res
 
@@ -599,7 +602,7 @@ class ControlNet(Model):
         self.add_output("controlnet_residuals", TensorType())
 
     def load(self, device: Any = None) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         params = quantize_mmdit_params(init_controlnet(
             jax.random.PRNGKey(stable_hash(self.model_id) % 2**31), cfg
         ))
@@ -665,7 +668,7 @@ class ControlNet(Model):
         lat, cond, emb, t, sizes = stacked
         if sum(sizes) % mesh.size:
             return None
-        cfg = self.family.toy
+        cfg = self.family.dit
         axis = mesh.axis_names[0]
         cache = _mesh_fn_cache(model_components)
         key = ("cn", mesh)
@@ -715,7 +718,7 @@ class VAEDecode(Model):
         self.add_output("image", Image)
 
     def load(self, device: Any = None) -> Dict[str, Any]:
-        cfg = self.family.toy
+        cfg = self.family.dit
         params = init_vae(
             jax.random.PRNGKey(stable_hash(f"vae:{self.family.name}") % 2**31),
             latent_channels=cfg.latent_channels,
@@ -798,7 +801,7 @@ class VAEEncode(Model):
 
     def _as_array(self, img: Any) -> jnp.ndarray:
         if not hasattr(img, "shape"):   # toy stand-in for a PIL image
-            cfg = self.family.toy
+            cfg = self.family.dit
             img = jnp.zeros((1, cfg.latent_size * 8, cfg.latent_size * 8, 3))
         return img
 
@@ -960,7 +963,7 @@ class DenoiseSegment(Model):
         comps: Dict[str, Any] = {
             "backbone": self.backbone.load(device),
             "cns": [cn.load(device) for cn in self.cns],
-            "cfg": self.family.toy,
+            "cfg": self.family.dit,
             # donation is baked into the jit at load time (REPRO_DONATE);
             # execute() consults this marker for the copy-on-first-chunk
             # guard
@@ -991,7 +994,7 @@ class DenoiseSegment(Model):
         scan takes the stacked LoRA factors plus a per-row adapter index,
         and every step applies each row's adapter against the shared base
         params (no fold) — cross-tenant requests share one segment."""
-        cfg = self.family.toy
+        cfg = self.family.dit
         uses_cfg = self.family.uses_cfg
         n_cns = len(self.cns)
 
@@ -1018,7 +1021,7 @@ class DenoiseSegment(Model):
                 else:
                     res = jnp.zeros(
                         (cfg.n_layers, lat.shape[0], cfg.image_tokens,
-                         cfg.d_model), lat.dtype)
+                         cfg.d_model), cfg.dtype)
                 if uses_cfg:
                     v = fused_cfg_velocity(
                         bb_apply, pb, lat, t, emb, guidance, res)
@@ -1169,7 +1172,7 @@ class DenoiseSegment(Model):
         sharding inside the scan)."""
         rows = batch_size * (2 if self.family.uses_cfg else 1)
         for j in range(k, 0, -1):
-            if rows % j == 0 or seq_shard_divisor(self.family.toy, j):
+            if rows % j == 0 or seq_shard_divisor(self.family.dit, j):
                 return j
         return 1
 
@@ -1195,7 +1198,7 @@ class DenoiseSegment(Model):
         lat, emb, cond, t_mid, t_cur, t_next, guidance, sizes = stacked
         rows = int(lat.shape[0]) * (2 if self.family.uses_cfg else 1)
         k = mesh.size
-        if rows % k and not seq_shard_divisor(self.family.toy, k):
+        if rows % k and not seq_shard_divisor(self.family.dit, k):
             return None      # neither row-DP nor sequence sharding fits
         cache = _mesh_fn_cache(model_components)
         key = ("segment", mesh)
@@ -1213,7 +1216,7 @@ class DenoiseSegment(Model):
         return [{"latents": chunk} for chunk in _split_rows(out, sizes)]
 
     def _make_sharded_scan(self, mesh: Any) -> Any:
-        cfg = self.family.toy
+        cfg = self.family.dit
         uses_cfg = self.family.uses_cfg
         n_cns = len(self.cns)
         k = mesh.size
@@ -1266,7 +1269,7 @@ class DenoiseSegment(Model):
                 else:
                     res2 = jnp.zeros(
                         (cfg.n_layers, lat2.shape[0], cfg.image_tokens,
-                         cfg.d_model), lat.dtype)
+                         cfg.d_model), cfg.dtype)
                 if row_dp:
                     v2 = bb_sharded(pb, lat2, t2, emb_b, res2)
                 else:
@@ -1337,7 +1340,7 @@ class LoRAAdapter(Model):
 
     def load(self, device: Any = None) -> Dict[str, Any]:
         key = jax.random.PRNGKey(stable_hash(self.model_id) % 2**31)
-        lora = init_lora(key, self.family.toy, rank=self.rank)
+        lora = init_lora(key, self.family.dit, rank=self.rank)
         # quantized factors (REPRO_QUANT): the AdapterPool's byte budget
         # and the proc plane's adapter ships both see the small form
         return {
@@ -1346,7 +1349,7 @@ class LoRAAdapter(Model):
             # folded into the last layer's wo); unused unless the adapter
             # is attached to the text encoder as well
             "text_lora": quantize_text_lora(init_text_lora(
-                jax.random.fold_in(key, 1), self.family.toy.text_dim,
+                jax.random.fold_in(key, 1), self.family.dit.text_dim,
                 rank=self.rank)),
         }
 

@@ -18,34 +18,20 @@ Usage:
 """
 
 import argparse
-import dataclasses
 import sys
-from typing import Any, Dict
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.diffusion.config import DiTConfig
+from repro.diffusion.config import FAMILIES
 from repro.diffusion.mmdit import init_mmdit, mmdit_apply
 from repro.launch.dryrun import analyze
 from repro.launch.mesh import make_production_mesh
 
-# Real-scale backbone geometries (approximate published configs; the
-# two-stream MMDiT block slightly over-parameterizes Flux's mixed
-# joint/single-stream stack — noted in DESIGN.md).
-REAL = {
-    "sd3": DiTConfig(d_model=1536, n_layers=24, n_heads=24, d_ff=6144,
-                     text_dim=4096, latent_size=128, latent_channels=16,
-                     patch=2, text_tokens=333, dtype=jnp.bfloat16),
-    "sd3.5-large": DiTConfig(d_model=2432, n_layers=38, n_heads=38,
-                             d_ff=9728, text_dim=4096, latent_size=128,
-                             latent_channels=16, patch=2, text_tokens=333,
-                             dtype=jnp.bfloat16),
-    "flux-dev": DiTConfig(d_model=3072, n_layers=57, n_heads=24, d_ff=12288,
-                          text_dim=4096, latent_size=128, latent_channels=16,
-                          patch=2, text_tokens=512, dtype=jnp.bfloat16),
-}
+# published backbone geometries (flux-schnell shares flux-dev's)
+REAL = {f: FAMILIES[f].published for f in ("sd3", "sd3.5-large", "flux-dev")}
 
 _DOWN = ("wo", "w2", "final_proj")
 

@@ -23,10 +23,11 @@ def main() -> None:
     ap.add_argument("--no-admission", action="store_true")
     args = ap.parse_args()
 
-    from repro.core import LocalBackend, ServingSystem
+    from repro.core import LocalBackend, ServingSystem, use_compile_cache
     from repro.diffusion import table2_setting
     from repro.sim import generate_trace
 
+    use_compile_cache()
     wfs = table2_setting(args.setting)
     backend = LocalBackend() if args.plane == "local" else None
     sys_ = ServingSystem(n_executors=args.executors,

@@ -24,10 +24,10 @@ Params = Dict[str, Any]
 # ------------------------------------------------- flash-attention routing
 #
 # The non-causal, mask-free attention path (the MMDiT joint text+image
-# hot path, the text encoder) routes through the Pallas flash-attention
-# kernel (repro.kernels.flash_attention) — interpret mode on CPU, compiled
-# Mosaic on TPU.  ``REPRO_FLASH_ATTENTION=0`` (or set_flash_attention(False))
-# falls back to the pure-jnp reference path.
+# hot path, the text encoder) routes through ``mha`` of
+# repro.kernels.flash_attention: the Pallas kernel — interpret mode on CPU,
+# compiled Mosaic on TPU — or, under ``REPRO_FLASH_ATTENTION=0`` (or
+# set_flash_attention(False)), the kernel's pure-jnp oracle.
 
 _flash_enabled: bool = os.environ.get(
     "REPRO_FLASH_ATTENTION", "1").lower() not in ("0", "false", "off")
@@ -106,16 +106,10 @@ def qdense(h: jax.Array, w) -> jax.Array:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX versions: top-level with ``check_vma``
-    on current releases, ``jax.experimental.shard_map`` with ``check_rep``
-    on older ones (e.g. 0.4.x, which has no ``jax.shard_map`` at all)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off (the bodies mix
+    replicated and sharded operands by design)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ----------------------------------------------------- grouped LoRA dense
@@ -348,16 +342,16 @@ def gqa_attention(
     group = hq // hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
     # non-causal, mask-free joint-sequence attention (the MMDiT hot path):
-    # Pallas flash-attention kernel, unless the config flag routes the
-    # reference path.  Long sequences keep the dedicated blockwise paths.
+    # the Pallas flash-attention kernel, or its oracle when the flag routes
+    # the reference.  Long sequences keep the dedicated blockwise paths.
     # (The sharded sequence-parallel rectangle — local queries against the
     # all-gathered K/V — calls ``mha`` directly; see _mmdit_block_seq.)
-    if (_flash_enabled and not causal and window is None and mask is None
+    if (not causal and window is None and mask is None
             and q_offset == 0 and softmax_scale is None and sq == sk
             and sq <= 8192):
         from repro.kernels.flash_attention.ops import mha
 
-        return mha(q, k, v, causal=False)
+        return mha(q, k, v, causal=False, use_kernel=_flash_enabled)
     # decode against a long cache: grouped blockwise path (never
     # materializes the repeated-KV or the f32 full cache)
     if mask is not None and sk > 8192 and sq <= 128:

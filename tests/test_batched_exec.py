@@ -28,7 +28,7 @@ from repro.nn.layers import gqa_attention, set_flash_attention
 
 KEY = jax.random.PRNGKey(0)
 FAM = FAMILIES["sd3"]
-CFG = FAM.toy
+CFG = FAM.dit
 
 
 def _batch_kwargs_backbone(n, with_residuals=False):

@@ -58,7 +58,7 @@ def test_seed_determinism():
 
 
 def test_lora_fold_unfold_roundtrip():
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     params = init_mmdit(jax.random.PRNGKey(0), cfg)
     lora = randomize_lora(jax.random.PRNGKey(1),
                           init_lora(jax.random.PRNGKey(2), cfg))
@@ -73,7 +73,7 @@ def test_lora_fold_unfold_roundtrip():
 
 
 def test_controlnet_residuals_modulate_backbone():
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     params = init_mmdit(jax.random.PRNGKey(0), cfg)
     lat = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16, 4))
     emb = jax.random.normal(jax.random.PRNGKey(2), (1, 8, 64))

@@ -33,7 +33,7 @@ def test_latent_parallel_cfg_matches_sequential():
         from repro.diffusion.config import FAMILIES
         from repro.diffusion.mmdit import init_mmdit
         from repro.diffusion.sampler import cfg_velocity, latent_parallel_velocity
-        cfg = FAMILIES['sd3'].toy
+        cfg = FAMILIES['sd3'].dit
         params = init_mmdit(jax.random.PRNGKey(0), cfg)
         lat = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16, 4))
         emb = jax.random.normal(jax.random.PRNGKey(2), (1, 8, 64))

@@ -76,7 +76,7 @@ def test_sharded_backend_single_device_degrades_to_local():
     backend = ShardedBackend(mm)
     assert not backend.enabled
     ms = ModelSet(FAMILIES["sd3"])
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     kw = {"latents": jax.random.normal(
               jax.random.PRNGKey(0),
               (1, cfg.latent_size, cfg.latent_size, cfg.latent_channels)),
@@ -118,7 +118,7 @@ def test_backbone_sharded_parity(k, n_req):
     ms = ModelSet(FAMILIES["sd3"])
     mm = MeshManager()
     backend = ShardedBackend(mm)
-    kws = _backbone_kwargs(n_req, FAMILIES["sd3"].toy)
+    kws = _backbone_kwargs(n_req, FAMILIES["sd3"].dit)
     ref, _, _ = backend.execute_batch(ms.backbone, [dict(kw) for kw in kws])
     mesh = mm.submesh(list(range(k)))
     out, _, _ = backend.execute_batch(ms.backbone, [dict(kw) for kw in kws],
@@ -139,7 +139,7 @@ def test_seq_sharded_mmdit_device_placement_and_parity():
     from repro.diffusion import FAMILIES
     from repro.diffusion.mmdit import init_mmdit, mmdit_apply, mmdit_apply_seq_sharded
 
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     params = init_mmdit(jax.random.PRNGKey(0), cfg)
     lat = jax.random.normal(jax.random.PRNGKey(1),
                             (2, cfg.latent_size, cfg.latent_size,
@@ -162,7 +162,7 @@ def test_controlnet_and_vae_sharded_parity():
     from repro.diffusion import FAMILIES, ModelSet
 
     fam = FAMILIES["sd3"]
-    cfg = fam.toy
+    cfg = fam.dit
     ms = ModelSet(fam)
     mm = MeshManager()
     backend = ShardedBackend(mm)
@@ -207,7 +207,7 @@ def test_indivisible_batch_falls_back_to_single_device():
     ms = ModelSet(FAMILIES["sd3"])
     mm = MeshManager()
     backend = ShardedBackend(mm)
-    kws = _backbone_kwargs(1, FAMILIES["sd3"].toy)
+    kws = _backbone_kwargs(1, FAMILIES["sd3"].dit)
     out, _, _ = backend.execute_batch(ms.backbone, [dict(kw) for kw in kws],
                                       mesh=mm.submesh([0, 1, 2]))
     assert backend.shard_log == []          # declined -> single-device path

@@ -142,7 +142,7 @@ def test_quantize_roundtrip_and_shrink(mode):
 
 
 def test_quantize_mmdit_params_shrinks_stream_weights():
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     params = init_mmdit(KEY, cfg)
     with _quant("int8"):
         qparams = quantize_mmdit_params(params)
@@ -223,10 +223,10 @@ def test_profile_prices_quantized_forwards():
     """Quantizable models get the modeled MXU/residency win (int8: 2x
     issue rate + halved weight stream; fp8: residency only); VAEs price
     identically in every mode."""
-    from repro.core import ProfileStore
+    from repro.core import GPU_H800, ProfileStore
     from repro.diffusion.ops import DiffusionBackbone, VAEDecode
 
-    store = ProfileStore()
+    store = ProfileStore(GPU_H800)
     bb = store.profile_model(DiffusionBackbone(FAMILIES["sd3"]))
     vae = store.profile_model(VAEDecode(FAMILIES["sd3"]))
     with _quant("off"):
@@ -266,7 +266,7 @@ def test_denoised_latent_parity(mode):
     """The ISSUE gate: quantized multi-step denoise stays within
     LATENT_TOL relative of the fp32 trajectory (errors compound across
     steps — this is the honest end-of-chain number, not one matmul)."""
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     params = init_mmdit(KEY, cfg)
     want = _denoised_latents(params, cfg)
     with _quant(mode):

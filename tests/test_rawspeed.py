@@ -37,7 +37,7 @@ def _segment(steps=3):
 
 
 def _seg_kwargs(seg, b=2):
-    cfg = seg.family.toy
+    cfg = seg.family.dit
     ks = jax.random.split(KEY, 2)
     lat = jax.random.normal(
         ks[0], (b, cfg.latent_size, cfg.latent_size, cfg.latent_channels))

@@ -239,7 +239,7 @@ def test_segment_batches_mixed_progress():
     ms = ModelSet(FAMILIES["sd3"])
     seg = ms.backbone.build_segment([], 4)
     comps = seg.load()
-    cfg = FAMILIES["sd3"].toy
+    cfg = FAMILIES["sd3"].dit
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     sched = [1.0, 0.75, 0.5, 0.25, 0.0]
     kws = []
@@ -278,7 +278,7 @@ def test_segment_sharded_parity_k2(n_cns):
     from repro.core import MeshManager, ShardedBackend
 
     fam = FAMILIES["sd3"]
-    cfg = fam.toy
+    cfg = fam.dit
     ms = ModelSet(fam)
     seg = ms.backbone.build_segment([ms.cn1][:n_cns], 3)
     mm = MeshManager()

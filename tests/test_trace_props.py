@@ -8,7 +8,7 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need the optional hypothesis dependency")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import ProfileStore
+from repro.core import GPU_H800, ProfileStore
 from repro.core.datastore import DataEngine
 from repro.sim import gamma_interarrivals, generate_trace
 from repro.sim.trace import skewed_popularity
@@ -35,7 +35,7 @@ def test_popularity_is_distribution(n, alpha):
 @settings(max_examples=40, deadline=None)
 def test_data_engine_refcount_invariant(data):
     """Values vanish exactly when their last consumer releases them."""
-    engine = DataEngine(ProfileStore())
+    engine = DataEngine(ProfileStore(GPU_H800))
     n = data.draw(st.integers(1, 10))
     keys = []
     for i in range(n):
@@ -53,7 +53,7 @@ def test_data_engine_refcount_invariant(data):
 @given(st.lists(st.integers(0, 3), min_size=1, max_size=8))
 @settings(max_examples=25, deadline=None)
 def test_fetch_is_idempotent_per_executor(placements):
-    engine = DataEngine(ProfileStore())
+    engine = DataEngine(ProfileStore(GPU_H800))
     engine.put("k", executor_id=0, nbytes=10**6, refcount=100)
     total_before = engine.bytes_transferred
     for e in placements:
