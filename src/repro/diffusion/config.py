@@ -2,20 +2,22 @@
 
 Each family carries:
 
-* ``real-scale`` statistics — parameter counts / token geometry of the
-  published checkpoints, used by the analytic latency profiles, the
-  monolithic baselines, and the roofline analysis;
+* ``real-scale`` statistics — parameter counts of the published
+  checkpoints, used with ``published`` by the analytic latency profiles,
+  the monolithic baselines, and the roofline analysis;
+* ``published`` — the backbone geometry of the published checkpoint: an
+  MMDiT's full :class:`DiTConfig` in bf16, which
+  :meth:`DiffusionFamily.at_published_width` swaps in for ``dit`` to serve
+  the family at its real width, or a :class:`UNetGeometry` for a UNet
+  (SDXL), which only the cost model reads;
 * ``dit`` — the backbone configuration the executable plane runs: the
-  CPU-sized toy by default, used by the correctness tests;
-* ``published`` — the backbone geometry of the published checkpoint in
-  bf16, which :meth:`DiffusionFamily.at_published_width` swaps in for
-  ``dit`` to serve the family at its real width.
+  CPU-sized toy by default, used by the correctness tests.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Union
 
 import jax.numpy as jnp
 
@@ -49,6 +51,24 @@ class DiTConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class UNetGeometry:
+    """Width, depth and token counts of a UNet backbone, which has no
+    MMDiT config here; the cost model reads the same fields as a
+    :class:`DiTConfig`'s."""
+
+    d_model: int
+    n_layers: int
+    image_tokens: int
+    text_tokens: int
+
+
+_TOY = DiTConfig(
+    d_model=64, n_layers=2, n_heads=4, d_ff=256, text_dim=64,
+    latent_size=16, latent_channels=4, patch=2, text_tokens=8,
+)
+
+
+@dataclasses.dataclass(frozen=True)
 class DiffusionFamily:
     """One base-model family from Table 2 (SD3, SD3.5-Large, Flux-*)."""
 
@@ -59,18 +79,22 @@ class DiffusionFamily:
     controlnet_params: float
     denoise_steps: int
     uses_cfg: bool                # classifier-free guidance (2 passes/step)
-    image_tokens: int             # 1024px -> 4096 tokens (patch-2 on /8 VAE)
-    text_tokens: int
-    d_model_real: int
-    n_layers_real: int
-    dit: DiTConfig = None         # executable backbone config
-    published: Optional[DiTConfig] = None   # published backbone geometry
+    published: Union[DiTConfig, UNetGeometry]   # published backbone geometry
+    dit: DiTConfig = _TOY         # executable backbone config
+
+    @property
+    def image_tokens(self) -> int:    # 1024px -> 4096 (patch-2 on /8 VAE)
+        return self.published.image_tokens
+
+    @property
+    def text_tokens(self) -> int:
+        return self.published.text_tokens
 
     def at_published_width(self) -> "DiffusionFamily":
         """This family with its published backbone geometry as the
         executable config (same name, so workflows and model ids match)."""
-        if self.published is None:
-            raise ValueError(f"{self.name}: no published backbone geometry")
+        if not isinstance(self.published, DiTConfig):
+            raise ValueError(f"{self.name}: no published MMDiT geometry")
         return dataclasses.replace(self, dit=self.published)
 
     @property
@@ -111,19 +135,15 @@ class DiffusionFamily:
 
     def latent_bytes(self) -> float:
         # latent tensor (e.g. 128x128x16 fp16)
-        return self.image_tokens * 4 * self.d_model_real / self.n_layers_real  # ~0.5-2MB
+        g = self.published
+        return self.image_tokens * 4 * g.d_model / g.n_layers  # ~0.5-2MB
 
     def controlnet_residual_bytes(self) -> float:
         """Residual feature maps transferred per denoising step."""
-        inj_layers = max(1, self.n_layers_real // 2)
-        tokens = self.image_tokens + self.text_tokens
-        return inj_layers * tokens * self.d_model_real * 2.0
+        g = self.published
+        inj_layers = max(1, g.n_layers // 2)
+        return inj_layers * (g.image_tokens + g.text_tokens) * g.d_model * 2.0
 
-
-_TOY = DiTConfig(
-    d_model=64, n_layers=2, n_heads=4, d_ff=256, text_dim=64,
-    latent_size=16, latent_channels=4, patch=2, text_tokens=8,
-)
 
 # Published backbone geometries in bf16: 1024px images (128x128x16
 # latents, patch 2 -> 4096 image tokens).  The two-stream MMDiT block
@@ -152,11 +172,6 @@ SD3 = DiffusionFamily(
     controlnet_params=1.0e9,
     denoise_steps=28,
     uses_cfg=True,
-    image_tokens=4096,
-    text_tokens=333,
-    d_model_real=1536,
-    n_layers_real=24,
-    dit=_TOY,
     published=SD3_DIT,
 )
 
@@ -168,11 +183,6 @@ SD35_LARGE = DiffusionFamily(
     controlnet_params=2.5e9,
     denoise_steps=40,
     uses_cfg=True,
-    image_tokens=4096,
-    text_tokens=333,
-    d_model_real=2432,
-    n_layers_real=38,
-    dit=_TOY,
     published=SD35_LARGE_DIT,
 )
 
@@ -184,11 +194,6 @@ FLUX_DEV = DiffusionFamily(
     controlnet_params=0.72e9,       # ~6% of base (paper §7.3)
     denoise_steps=28,
     uses_cfg=False,                 # guidance-distilled
-    image_tokens=4096,
-    text_tokens=512,
-    d_model_real=3072,
-    n_layers_real=57,
-    dit=_TOY,
     published=FLUX_DIT,
 )
 
@@ -200,11 +205,6 @@ FLUX_SCHNELL = DiffusionFamily(
     controlnet_params=0.72e9,
     denoise_steps=4,                # timestep-distilled
     uses_cfg=False,
-    image_tokens=4096,
-    text_tokens=512,
-    d_model_real=3072,
-    n_layers_real=57,
-    dit=_TOY,
     published=FLUX_DIT,
 )
 
@@ -220,10 +220,7 @@ SDXL = DiffusionFamily(
     controlnet_params=1.25e9,
     denoise_steps=30,
     uses_cfg=True,
-    image_tokens=4096,
-    text_tokens=77,
-    d_model_real=1280,
-    n_layers_real=70,
-    dit=_TOY,
+    published=UNetGeometry(d_model=1280, n_layers=70, image_tokens=4096,
+                           text_tokens=77),
 )
 FAMILIES["sdxl"] = SDXL

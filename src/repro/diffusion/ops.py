@@ -555,11 +555,12 @@ class DiffusionBackbone(Model):
 
     def cost(self) -> ModelCost:
         f = self.family
+        g = f.published
         tokens = f.image_tokens + f.text_tokens
         return ModelCost(
             flops_per_item=f.backbone_step_flops(),
             param_bytes=f.backbone_bytes(),
-            act_io_bytes=12.0 * f.n_layers_real * tokens * f.d_model_real * 2.0,
+            act_io_bytes=12.0 * g.n_layers * tokens * g.d_model * 2.0,
             output_bytes=f.image_tokens * 16 * 2.0,
             # k_max profiled for the sharded plane: 2x from the CFG/latent
             # branch split, 2x more from batch-row or sequence sharding
@@ -570,9 +571,8 @@ class DiffusionBackbone(Model):
             # targets × n_layers, two skinny matmuls per row per rank;
             # per-adapter HBM traffic is the bf16 A/B factor stream
             lora_rank=8,
-            lora_flops_per_rank=16.0 * f.n_layers_real * f.image_tokens
-            * f.d_model_real,
-            lora_bytes_per_adapter=16.0 * f.n_layers_real * f.d_model_real * 8,
+            lora_flops_per_rank=16.0 * g.n_layers * f.image_tokens * g.d_model,
+            lora_bytes_per_adapter=16.0 * g.n_layers * g.d_model * 8,
             # stream projections quantize (REPRO_QUANT): the roofline
             # prices int8 forwards at the doubled MXU issue rate and the
             # halved weight stream
@@ -689,11 +689,12 @@ class ControlNet(Model):
 
     def cost(self) -> ModelCost:
         f = self.family
+        g = f.published
         return ModelCost(
             flops_per_item=f.controlnet_step_flops(),
             param_bytes=f.controlnet_bytes(),
-            act_io_bytes=6.0 * f.n_layers_real * (f.image_tokens + f.text_tokens)
-            * f.d_model_real,
+            act_io_bytes=6.0 * g.n_layers * (f.image_tokens + f.text_tokens)
+            * g.d_model,
             output_bytes=f.controlnet_residual_bytes(),
             max_parallelism=2,           # batch-axis data parallelism
             max_batch=8,
