@@ -1,0 +1,12 @@
+"""Device time of the DenoiseSegment program per CFG row per denoising
+step, in ms (device trace; steps from the dispatch log)."""
+
+from chipbench import xplane
+
+
+def read(r):
+    dev, steps = r.device(), r.request_steps()
+    if dev is None or not steps:
+        return None
+    seconds, runs = xplane.program_seconds(dev, r.programs["segment"])
+    return 1e3 * seconds / (2 * steps) if runs else None
