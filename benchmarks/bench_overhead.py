@@ -39,9 +39,9 @@ class _PlaneArm:
 
     A warm-up wave with the identical arrival pattern runs at build time
     so every (model, batch-size) jit variant is compiled before
-    measurement.  Dispatch overhead is control-plane handler time MINUS
-    measured device seconds — the coordinator executes batches inside its
-    handlers on this plane."""
+    measurement.  Dispatch overhead is the coordinator's control-plane
+    time per dispatch, which leaves out the backend execution inside its
+    handlers."""
 
     def __init__(self, n_requests: int, max_batch_cap: int, steps: int = 3):
         self.n_requests = n_requests
@@ -87,7 +87,6 @@ class _PlaneArm:
         n_fwd = len(self.backend.forward_log)
         n_disp = len(coord.dispatch_log)
         cp0 = coord.control_plane_time
-        ex0 = self.backend.exec_seconds
         wall = self._wave("measured wave")
         self.waves.append(wall)
         if len(self.waves) == 1:
@@ -95,8 +94,7 @@ class _PlaneArm:
             self.forwards = len(self.backend.forward_log) - n_fwd
             self.dispatches = len(coord.dispatch_log) - n_disp
         cp = coord.control_plane_time - cp0
-        ex = self.backend.exec_seconds - ex0
-        self.overhead += (max(0.0, cp - ex) / max(1, self.dispatches)
+        self.overhead += (cp / max(1, self.dispatches)
                           - self.overhead) / len(self.waves)   # running mean
 
     @property

@@ -24,6 +24,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 from repro.core.model import Model
 from repro.core.profiles import ProfileStore
 from repro.core.telemetry import FoldCacheEviction, default_registry
+from repro.core.tracing import BACKEND_DEVICE_WAIT, host_span
 
 # Lifecycle states (autoscaler-managed; a fixed fleet stays SERVING forever):
 #
@@ -377,8 +378,8 @@ class LocalBackend:
     * base components per ``model_id`` (includes LoRA adapters — an
       adapter's ``load()`` runs once, not once per denoising step);
     * LoRA-folded parameter sets per ``(model_id, patch_ids)`` placement —
-      a TRUE LRU under ``folded_budget_bytes`` (evictions append
-      ``("evict:<model_id>", 0)`` markers to ``forward_log``), so
+      a TRUE LRU under ``folded_budget_bytes`` (each eviction emits a
+      :class:`FoldCacheEviction` on the telemetry registry), so
       per-placement folds can no longer grow without bound;
     * an :class:`AdapterPool` of decoded A/B factors backing the unfolded
       grouped multi-LoRA route (mixed-adapter batches never fold).
@@ -467,13 +468,9 @@ class LocalBackend:
             victim, _ = self._folded.popitem(last=False)
             self._folded_bytes.pop(victim, None)
             self.folded_evictions += 1
-            # typed event on the telemetry registry is the primary
-            # eviction signal; the stringly forward_log marker stays as
-            # a compat shim for pre-telemetry consumers
             default_registry().emit(FoldCacheEviction(
                 model_id=victim[0], patch_ids=victim[1],
                 resident_bytes=sum(self._folded_bytes.values())))
-            self.forward_log.append((f"evict:{victim[0]}", 0))
         return folded, load_dt
 
     @property
@@ -502,8 +499,9 @@ class LocalBackend:
         (out of memory, a failed kernel) propagates to the caller."""
         import jax
 
-        jax.block_until_ready(
-            [x for x in jax.tree.leaves(out) if isinstance(x, jax.Array)])
+        with host_span(BACKEND_DEVICE_WAIT):
+            jax.block_until_ready(
+                [x for x in jax.tree.leaves(out) if isinstance(x, jax.Array)])
 
     def execute(self, model: Model, **kwargs: Any) -> Tuple[Dict[str, Any], float]:
         self._maybe_inject_fault()

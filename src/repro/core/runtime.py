@@ -26,6 +26,7 @@ quarantine, and opt-in replication of committed segment state.
 
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import os
@@ -57,7 +58,15 @@ from repro.core.faults import (
 from repro.core.profiles import ProfileStore, node_infer_time
 from repro.core.scheduler import ScheduledBatch, Scheduler
 from repro.core.telemetry import MetricsRegistry, default_registry
-from repro.core.tracing import COORDINATOR_PID, make_tracer
+from repro.core.tracing import (
+    BACKEND_EXECUTE,
+    COORDINATOR_DISPATCH,
+    COORDINATOR_EVENT,
+    COORDINATOR_PID,
+    SCHEDULER_CYCLE,
+    host_span,
+    make_tracer,
+)
 from repro.core.transport import StagedInput, WorkerDied
 from repro.core.types import ValueRef, nbytes_of
 
@@ -65,6 +74,11 @@ PENDING, READY, RUNNING, AWAITING, DONE = "pending", "ready", "running", "awaiti
 SHED = "shed"   # terminal: the node's request was shed (retry budget/strand)
 
 _seq = itertools.count()
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str, **args: Any) -> contextlib.nullcontext:
+    return _NO_SPAN
 
 # -------------------------------------------------- pipeline overlap flag
 #
@@ -98,7 +112,7 @@ class RequestNode:
     __slots__ = (
         "request", "node", "uid", "state", "pending_eager", "deferred_arrivals",
         "own_done_time", "executor_ids", "seq", "infer_est", "dispatch_time",
-        "ready_since", "seg_done", "seg_state", "seg_pending",
+        "ready_since", "ready_wall", "seg_done", "seg_state", "seg_pending",
         "retries", "dispatch_seq", "seg_commit",
     )
 
@@ -116,6 +130,7 @@ class RequestNode:
         self.infer_est = infer_est
         self.dispatch_time: Optional[float] = None
         self.ready_since: Optional[float] = None   # queueing-delay signal
+        self.ready_wall: Optional[float] = None    # the same, host clock
         # segment progress (DenoiseSegment nodes execute in load-adaptive
         # chunks): steps already committed, the carried latent between
         # chunks, and the not-yet-committed result of the running chunk
@@ -298,7 +313,10 @@ class Coordinator:
         self.finished: List[Request] = []
         self.rejected: List[Request] = []
         self._rid = itertools.count()
-        self.control_plane_time = 0.0     # wall seconds spent in handlers
+        # host seconds in the event handlers, less the backend execution
+        # inside them (backend_time: the wall time of _execute_real)
+        self.control_plane_time = 0.0
+        self.backend_time = 0.0
         self.dispatch_log: List[ScheduledBatch] = []
         self._adapters_cached: set = set()
         # ------------------------------------------------- chaos/hardening
@@ -351,6 +369,10 @@ class Coordinator:
         # cost (their ``self.n_x += 1`` call sites are untouched).
         self.tracer = tracer if tracer is not None else make_tracer()
         self._tele: bool = self.tracer.enabled
+        # host spans on the profiler's clock (repro.core.tracing) mark the
+        # executable plane's boundaries; the sim plane has no device and
+        # does not import JAX
+        self._span = host_span if backend is not None else _no_span
         self.metrics = metrics if metrics is not None else default_registry()
         # executor id -> open dispatch-span record, closed at the first
         # of batch_done / batch_timeout / executor failure so slices on
@@ -370,7 +392,7 @@ class Coordinator:
         reg.register_object("coordinator", self, (
             "n_submitted", "n_timeouts", "n_transient_retries",
             "n_requeues", "n_stranded", "n_worker_deaths",
-            "n_heartbeat_deaths", "control_plane_time",
+            "n_heartbeat_deaths", "control_plane_time", "backend_time",
             "n_overlap_dispatches", "overlap_hidden_seconds"))
         reg.register_object("datastore", self.engine, (
             "bytes_transferred", "num_transfers", "num_local_hits",
@@ -494,12 +516,14 @@ class Coordinator:
                 break
             heapq.heappop(self.events)
             self.now = max(self.now, t)
-            t0 = _time.perf_counter()
-            getattr(self, f"_on_{kind}")(payload)
-            if kind != "autoscale_tick":
-                self._last_activity = self.now
-            self._schedule_cycle()
-            self.control_plane_time += _time.perf_counter() - t0
+            t0, b0 = _time.perf_counter(), self.backend_time
+            with self._span(COORDINATOR_EVENT, kind=kind):
+                getattr(self, f"_on_{kind}")(payload)
+                if kind != "autoscale_tick":
+                    self._last_activity = self.now
+                self._schedule_cycle()
+            self.control_plane_time += (_time.perf_counter() - t0
+                                        - (self.backend_time - b0))
         if (until is None and self.faults is not None and not self.events
                 and self.inflight):
             # run-to-completion with chaos on: the loop drained with work
@@ -599,6 +623,7 @@ class Coordinator:
         rnode.executor_ids = []
         rnode.own_done_time = None
         rnode.ready_since = self.now
+        rnode.ready_wall = _time.perf_counter()
         self.ready.append(rnode)
         return True
 
@@ -842,6 +867,7 @@ class Coordinator:
             rn.seg_pending = None        # uncommitted chunk re-runs
             rn.deferred_arrivals.clear()
             rn.ready_since = self.now
+            rn.ready_wall = _time.perf_counter()
             delay = self.retry.backoff(rn.retries) if count_retry else 0.0
             if delay > 0.0:
                 self._push(self.now + delay, "requeue_release",
@@ -1070,6 +1096,7 @@ class Coordinator:
         else:
             rnode.state = READY
             rnode.ready_since = self.now
+            rnode.ready_wall = _time.perf_counter()
             self.ready.append(rnode)
 
     def _overlap_candidates(self) -> List[Executor]:
@@ -1093,14 +1120,18 @@ class Coordinator:
     def _schedule_cycle(self) -> None:
         if not self.ready:
             return
-        free = [e for e in self.executors if e.is_free(self.now)]
-        # None = overlap off; [] = on but no mid-flight candidates yet
-        # (the scheduler may still mint in-cycle candidates from segment
-        # dispatches, which need a free executor anyway)
-        overlap_pool = self._overlap_candidates() if self.overlap else None
-        if not free and not overlap_pool:
-            return
-        if self.backend is not None:
+        with self._span(SCHEDULER_CYCLE):
+            free = [e for e in self.executors if e.is_free(self.now)]
+            # None = overlap off; [] = on but no mid-flight candidates yet
+            # (the scheduler may still mint in-cycle candidates from
+            # segment dispatches, which need a free executor anyway)
+            overlap_pool = (self._overlap_candidates() if self.overlap
+                            else None)
+            if not free and not overlap_pool:
+                return
+            if self.backend is None:
+                self._dispatch_cycle(free, overlap_pool)
+                return
             # executable plane really needs input VALUES: hold nodes whose
             # deferred producers have not finished (timing overlap is the
             # sim plane's concern; correctness rules here)
@@ -1120,8 +1151,6 @@ class Coordinator:
                 self._dispatch_cycle(free, overlap_pool)
             finally:
                 self.ready.extend(held)
-            return
-        self._dispatch_cycle(free, overlap_pool)
 
     def _dispatch_cycle(self, free, overlap_pool=None) -> None:
 
@@ -1138,10 +1167,14 @@ class Coordinator:
                                                   overlap=overlap_pool,
                                                   now=self.now)
         for d in decisions:
-            self._dispatch(d)
+            with self._span(COORDINATOR_DISPATCH, model=d.model_id,
+                            batch_size=d.batch_size,
+                            segment_steps=d.segment_steps):
+                self._dispatch(d)
 
 
     def _dispatch(self, batch: ScheduledBatch) -> None:
+        t_wall = _time.perf_counter()
         self.dispatch_log.append(batch)
         batch_index = self._batch_index
         self._batch_index += 1
@@ -1215,7 +1248,8 @@ class Coordinator:
                 # the backend itself raises; retry the stacked forward
                 # around the injected errors with capped backoff
                 try:
-                    real = self._execute_real_hardened(batch, attempts)
+                    real = self._timed(self._execute_real_hardened, batch,
+                                       attempts)
                 except WorkerDied as err:
                     self._abort_dispatch_on_death(batch, err)
                     return
@@ -1237,7 +1271,8 @@ class Coordinator:
                 duration += sum(self.retry.backoff(i) for i in range(1, retries + 1))
         elif self.backend is not None and fault != "hang":
             try:
-                duration = self._execute_real(batch) + batch.l_data + batch.patch_swap
+                duration = (self._timed(self._execute_real, batch)
+                            + batch.l_data + batch.patch_swap)
             except WorkerDied as err:
                 self._abort_dispatch_on_death(batch, err)
                 return
@@ -1304,10 +1339,16 @@ class Coordinator:
                 self._open_overlap[batch.executor_ids[0]] = record
             else:
                 self._open_batch[batch.executor_ids[0]] = record
-            h = self._h_queue_delay.labels(batch.model_id)
-            for rn in batch.nodes:
-                if rn.ready_since is not None:
-                    h.observe(self.now - rn.ready_since)
+        # ready -> dispatch, on the host clock where there is a device
+        # (the virtual clock adds modelled costs); the sim plane has only
+        # its virtual clock
+        h = self._h_queue_delay.labels(batch.model_id)
+        for rn in batch.nodes:
+            if self.backend is not None:
+                if rn.ready_wall is not None:
+                    h.observe(t_wall - rn.ready_wall)
+            elif rn.ready_since is not None:
+                h.observe(self.now - rn.ready_since)
         for rn in batch.nodes:
             rn.state = RUNNING
             rn.executor_ids = list(batch.executor_ids)
@@ -1334,6 +1375,14 @@ class Coordinator:
         self._handle_worker_death(err)
         self._requeue_nodes(batch.nodes, count_retry=True)
         self._push(self.now, "kick", None)
+
+    def _timed(self, execute: Any, *args: Any) -> Any:
+        """``execute(*args)``, its wall time added to ``backend_time``."""
+        t0 = _time.perf_counter()
+        try:
+            return execute(*args)
+        finally:
+            self.backend_time += _time.perf_counter() - t0
 
     def _execute_real_hardened(
         self, batch: ScheduledBatch, inject_attempts: int,
@@ -1449,30 +1498,33 @@ class Coordinator:
                             ok[port] = rn.request.ref_key(ref)
                     out_keys.append(ok)
                 batch_kwargs.append(kwargs)
-            if submesh is not None:
-                outs, load_dt, exec_dt = self.backend.execute_batch(
-                    op, batch_kwargs, patches=patches, mesh=submesh)
-            elif proc:
-                if trace_proc:
-                    # span context rides the exec RPC: the worker records
-                    # stage/forward spans relative to RPC receipt and the
-                    # backend rebases them onto this virtual timestamp.
-                    # Offset by the groups already executed this dispatch
-                    # (their virtual window is exactly their RPC wall) so
-                    # successive groups' spans never overlap on the track
-                    self.backend.trace_ctx = {
-                        "ts": self.now + total,
-                        "rids": sorted({rn.request.rid for rn in rns})}
-                try:
+            with host_span(BACKEND_EXECUTE):
+                if submesh is not None:
                     outs, load_dt, exec_dt = self.backend.execute_batch(
-                        op, batch_kwargs, patches=patches,
-                        executor_id=batch.executor_ids[0], out_keys=out_keys)
-                finally:
+                        op, batch_kwargs, patches=patches, mesh=submesh)
+                elif proc:
                     if trace_proc:
-                        self.backend.trace_ctx = None
-            else:
-                outs, load_dt, exec_dt = self.backend.execute_batch(
-                    op, batch_kwargs, patches=patches)
+                        # span context rides the exec RPC: the worker
+                        # records stage/forward spans relative to RPC
+                        # receipt and the backend rebases them onto this
+                        # virtual timestamp.  Offset by the groups already
+                        # executed this dispatch (their virtual window is
+                        # exactly their RPC wall) so successive groups'
+                        # spans never overlap on the track
+                        self.backend.trace_ctx = {
+                            "ts": self.now + total,
+                            "rids": sorted({rn.request.rid for rn in rns})}
+                    try:
+                        outs, load_dt, exec_dt = self.backend.execute_batch(
+                            op, batch_kwargs, patches=patches,
+                            executor_id=batch.executor_ids[0],
+                            out_keys=out_keys)
+                    finally:
+                        if trace_proc:
+                            self.backend.trace_ctx = None
+                else:
+                    outs, load_dt, exec_dt = self.backend.execute_batch(
+                        op, batch_kwargs, patches=patches)
             for rn, out in zip(rns, outs):
                 if is_segment:
                     # committed at batch_done (survives executor failure

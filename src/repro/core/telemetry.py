@@ -14,14 +14,19 @@ close to zero-cost as instrumentation gets.
 The registry also owns first-class instruments (labeled counter / gauge
 / histogram families) for signals that have no legacy attribute — e.g.
 the coordinator's queue-delay histogram — plus a bounded ring of
-**typed telemetry events** (:class:`FoldCacheEviction` replaces the
-stringly ``("evict:<model_id>", 0)`` forward-log markers as the primary
-eviction signal; the string marker remains as a compat shim).
+**typed telemetry events** (:class:`FoldCacheEviction` is the eviction
+signal of the backend's fold cache).
 
 Exported as a Prometheus-style text dump (:meth:`MetricsRegistry.
 to_prometheus`).  Gating: ``REPRO_TELEMETRY`` enables the *tracer*
 (:mod:`repro.core.tracing`); the registry itself is always live because
-scrape-time collection costs nothing until somebody scrapes.
+scrape-time collection costs nothing until somebody scrapes.  Two of the
+coordinator's signals are on the host clock in the executable plane:
+``coordinator_control_plane_time`` (host seconds in the event handlers,
+less ``coordinator_backend_time``, the backend execution inside them)
+and the ``coordinator_queue_delay_seconds`` histogram (ready to
+dispatch, per node); the sim plane keeps its virtual clock for the
+latter.
 
 Also home to :func:`validate_chrome_trace` — the CI gate that a
 Chrome-trace export parses, its slices nest per track, and its flows
@@ -176,9 +181,7 @@ class TelemetryEvent:
 
 @dataclasses.dataclass(frozen=True)
 class FoldCacheEviction(TelemetryEvent):
-    """A LoRA-folded parameter set left the backend's fold-cache LRU.
-    Replaces the stringly ``("evict:<model_id>", 0)`` forward-log marker
-    as the primary signal (the marker survives as a compat shim)."""
+    """A LoRA-folded parameter set left the backend's fold-cache LRU."""
 
     model_id: str
     patch_ids: Tuple[str, ...]

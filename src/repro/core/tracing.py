@@ -1,11 +1,30 @@
-"""Request-scoped span tracer — the timeline half of the telemetry plane.
+"""Request-scoped span tracer, and the host spans of the served path.
+
+The module has two halves, on two clocks.
+
+**Host spans** (:func:`host_span`) mark the layer boundaries of the
+executable plane's served path: one coordinator event, one scheduling
+cycle, one dispatch, one backend call, the host's wait for the device
+(the names are :data:`HOST_SPANS`).  Each is a
+``jax.profiler.TraceAnnotation``, so it records only while a JAX
+profiler session runs, into that session's trace, on the host clock the
+device ops are stamped on.  That is what lets an idle gap on the device
+be put down to the host work that held it.  With no session running a
+span costs about a microsecond and records nothing; it is not gated on
+``REPRO_TELEMETRY``.
+
+**The tracer** (:class:`Tracer`) is the scheduler's request timeline,
+for both planes.
 
 Every admitted request carries a **trace id** (its ``rid``) from
 admission through scheduler queueing, dispatch, segment chunks, retries,
 quarantines, and recovery replays.  The coordinator records spans in
 **virtual time** (its event-loop clock), so the same schema covers both
-planes: sim arms get timelines for free, and the executable plane's
-measured wall durations *are* its virtual durations.
+planes: sim arms get timelines for free.  In the executable plane the
+virtual clock advances by the measured wall time of each dispatch plus
+the modelled data-fetch and patch-swap costs, so its exports cannot be
+lined up with a profiler trace; the host spans above are the wall-clock
+view.
 
 Worker processes (:mod:`repro.core.supervisor`) measure their spans in
 wall seconds **relative to RPC receipt**; the parent rebases them onto
@@ -40,13 +59,39 @@ from typing import Any, Dict, List, Optional, Tuple
 
 COORDINATOR_PID = 0
 
+# Host spans of the served path, outermost first; they nest in this order
+# on the coordinator's thread.
+COORDINATOR_EVENT = "coordinator.event"      # one _on_<kind> handler + cycle
+SCHEDULER_CYCLE = "scheduler.cycle"          # batch formation and dispatches
+COORDINATOR_DISPATCH = "coordinator.dispatch"  # one ScheduledBatch
+BACKEND_EXECUTE = "backend.execute"          # one backend execute_batch call
+BACKEND_DEVICE_WAIT = "backend.device_wait"  # the host waiting on the device
+HOST_SPANS = (COORDINATOR_EVENT, SCHEDULER_CYCLE, COORDINATOR_DISPATCH,
+              BACKEND_EXECUTE, BACKEND_DEVICE_WAIT)
+
 __all__ = [
     "COORDINATOR_PID",
+    "HOST_SPANS",
     "NULL_TRACER",
     "NullTracer",
     "Tracer",
+    "host_span",
     "make_tracer",
 ]
+
+_annotation: Any = None
+
+
+def host_span(name: str, **args: Any) -> Any:
+    """A context manager that records ``name`` (one of
+    :data:`HOST_SPANS`) and ``args`` as a host event of the running JAX
+    profiler session; inert when no session runs."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
 
 
 class Tracer:

@@ -32,6 +32,7 @@ from repro.core import (
     processes_available,
 )
 from repro.core.executor import AdapterPool
+from repro.core.telemetry import FoldCacheEviction, default_registry
 from repro.core.passes import InlineTrivialPass, JitCompilePass, SegmentFusionPass
 from repro.core.registry import WorkflowRegistry
 from repro.diffusion import FAMILIES, ModelSet, make_basic_workflow, make_lora_workflow
@@ -227,6 +228,8 @@ class _StubModel:
 
 
 def test_fold_cache_lru_eviction_markers():
+    reg = default_registry()
+    before = len(reg.events_of(FoldCacheEviction))
     be = LocalBackend(folded_budget_bytes=2.5 * 1024)
     base = _StubModel("base")
     folds = [[_StubPatch(f"p{i}")] for i in range(3)]
@@ -236,7 +239,8 @@ def test_fold_cache_lru_eviction_markers():
     be.components_for(base, folds[0])           # refresh placement 0
     be.components_for(base, folds[2])           # evicts placement 1 (LRU)
     assert be.folded_evictions == 1
-    assert ("evict:base", 0) in be.forward_log
+    evs = reg.events_of(FoldCacheEviction)[before:]
+    assert [(e.model_id, e.patch_ids) for e in evs] == [("base", ("p1",))]
     assert list(be._folded) == [("base", ("p0",)), ("base", ("p2",))]
     assert be.folded_resident_bytes <= 2.5 * 1024
 

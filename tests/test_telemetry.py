@@ -131,8 +131,8 @@ def test_registry_typed_events_ring_and_counter():
 
 
 def test_fold_cache_eviction_emits_typed_event_and_compat_marker():
-    """The typed event is the primary eviction signal; the stringly
-    ``("evict:<model_id>", 0)`` forward_log marker survives as a shim."""
+    """An eviction from the fold cache is signalled by one typed event
+    and leaves no entry in ``forward_log``, which counts forwards only."""
 
     class _StubModel:
         model_id = "base"
@@ -164,7 +164,7 @@ def test_fold_cache_eviction_emits_typed_event_and_compat_marker():
     assert evs[0].model_id == "base"
     assert evs[0].patch_ids == ("p1",)
     assert evs[0].resident_bytes > 0
-    assert ("evict:base", 0) in be.forward_log  # compat shim intact
+    assert len(be.forward_log) == 0
 
 
 # --------------------------------------------------------------------------
@@ -328,3 +328,116 @@ def test_tracing_does_not_change_output_bits():
         finally:
             configure(prev)
     np.testing.assert_array_equal(imgs[0], imgs[1])
+
+
+# --------------------------------------------------------------------------
+# host spans on the profiler's clock, and the host-clock counters
+# --------------------------------------------------------------------------
+
+def _serve_one(metrics=None):
+    """One toy-width ``sd3:basic`` request through the executable plane;
+    returns (system, image, host seconds of ``run``)."""
+    import time
+
+    from repro.diffusion import make_basic_workflow
+
+    sys_ = ServingSystem(n_executors=1, backend=LocalBackend(),
+                         metrics=metrics or MetricsRegistry())
+    wf = make_basic_workflow("sd3")
+    sys_.register(wf)
+    req = sys_.submit(wf.name, inputs={"seed": 0, "prompt": "a fox"},
+                      arrival=0.0, steps=3)
+    t0 = time.perf_counter()
+    sys_.run()
+    wall = time.perf_counter() - t0
+    assert req.status == "done"
+    key = req.ref_key(req.graph.outputs["image"])
+    return sys_, np.asarray(sys_.coordinator.engine.value_of(key)), wall
+
+
+def test_host_spans_nest_in_the_profiler_trace(tmp_path):
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.core.tracing import HOST_SPANS
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve_one()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    # name -> [(line, start, end, stats)]
+    spans = {name: [] for name in HOST_SPANS}
+    for i, line in enumerate(host.lines):
+        for e in line.events:
+            if e.name in spans:
+                spans[e.name].append((i, e.start_ns,
+                                      e.start_ns + e.duration_ns,
+                                      dict(e.stats)))
+    assert all(spans[name] for name in HOST_SPANS), \
+        {k: len(v) for k, v in spans.items()}
+    # each span lies inside one of the span above it, on the same thread
+    for outer, inner in zip(HOST_SPANS, HOST_SPANS[1:]):
+        for line, a, b, _ in spans[inner]:
+            assert any(ol == line and oa <= a and b <= ob
+                       for ol, oa, ob, _ in spans[outer]), (inner, outer)
+    assert {s["kind"] for *_, s in spans["coordinator.event"]} >= {"arrival"}
+    dispatched = [s for *_, s in spans["coordinator.dispatch"]]
+    assert all(set(s) == {"model", "batch_size", "segment_steps"}
+               for s in dispatched)
+    assert any(s["model"].startswith("segment:") for s in dispatched)
+
+
+def test_host_spans_do_not_change_output_bits(monkeypatch):
+    """With no profile session the spans are inert: the image is the
+    same, bit for bit, as with every span replaced by a no-op."""
+    import contextlib
+
+    import repro.core.executor as executor_mod
+    import repro.core.runtime as runtime_mod
+
+    _, with_spans, _ = _serve_one()
+    noop = lambda name, **args: contextlib.nullcontext()
+    monkeypatch.setattr(runtime_mod, "host_span", noop)
+    monkeypatch.setattr(executor_mod, "host_span", noop)
+    _, without, _ = _serve_one()
+    np.testing.assert_array_equal(with_spans, without)
+
+
+def test_control_plane_time_leaves_out_backend_time():
+    sys_, _, wall = _serve_one()
+    co = sys_.coordinator
+    # the backend's own measure (load and execute) lies inside the
+    # coordinator's wall time of the backend calls
+    assert 0 < co.backend.exec_seconds <= co.backend_time
+    assert 0 < co.control_plane_time < co.backend_time
+    assert co.control_plane_time + co.backend_time == \
+        pytest.approx(wall, rel=0.05)
+    assert f"coordinator_backend_time {co.backend_time:g}" \
+        in sys_.metrics_text()
+
+
+def test_queue_delay_observed_on_the_host_clock(monkeypatch, tele_off):
+    from repro.core.telemetry import Histogram
+
+    seen = []
+    observe = Histogram.observe
+
+    def record(self, v):
+        seen.append(v)
+        observe(self, v)
+
+    monkeypatch.setattr(Histogram, "observe", record)
+    reg = MetricsRegistry()
+    sys_, _, wall = _serve_one(reg)
+    nodes = sum(len(b.nodes) for b in sys_.coordinator.dispatch_log)
+    assert nodes > 0 and len(seen) == nodes
+    assert all(0 <= v <= wall for v in seen)
+    assert "coordinator_queue_delay_seconds_count" in reg.to_prometheus()
