@@ -2,7 +2,8 @@
 
 ``mha(q, k, v)`` takes the framework-wide ``[B, S, H, D]`` layout, handles
 GQA head expansion, and dispatches to the kernel (interpret mode on CPU,
-compiled Mosaic on TPU).
+compiled Mosaic on TPU).  The kernel picks its tiles from the shapes
+(``kernel.pick_block``).
 
 The kernel carries a ``custom_vjp``: the forward pass is the Pallas
 kernel, the backward pass recomputes through the pure-jnp reference
@@ -27,19 +28,17 @@ def _is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(qt, kt, vt, causal, window, block_q, block_k):
-    return flash_attention(
-        qt, kt, vt, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=not _is_tpu(),
-    )
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(qt, kt, vt, causal, window):
+    return flash_attention(qt, kt, vt, causal=causal, window=window,
+                           interpret=not _is_tpu())
 
 
-def _flash_fwd(qt, kt, vt, causal, window, block_q, block_k):
-    return _flash(qt, kt, vt, causal, window, block_q, block_k), (qt, kt, vt)
+def _flash_fwd(qt, kt, vt, causal, window):
+    return _flash(qt, kt, vt, causal, window), (qt, kt, vt)
 
 
-def _flash_bwd(causal, window, block_q, block_k, residuals, g):
+def _flash_bwd(causal, window, residuals, g):
     qt, kt, vt = residuals
     _, vjp = jax.vjp(
         lambda q, k, v: attention_ref(q, k, v, causal=causal, window=window),
@@ -53,7 +52,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_k", "use_kernel"),
+    static_argnames=("causal", "window", "use_kernel"),
 )
 def mha(
     q: jax.Array,               # [B, Sq, Hq, D]
@@ -62,8 +61,6 @@ def mha(
     *,
     causal: bool = False,
     window: Optional[int] = None,
-    block_q: int = 128,
-    block_k: int = 128,
     use_kernel: bool = True,
 ) -> jax.Array:
     b, sq, hq, d = q.shape
@@ -76,7 +73,7 @@ def mha(
     kt = k.transpose(0, 2, 1, 3).reshape(b * hq, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * hq, sk, d)
     if use_kernel:
-        out = _flash(qt, kt, vt, causal, window, block_q, block_k)
+        out = _flash(qt, kt, vt, causal, window)
     else:
         out = attention_ref(qt, kt, vt, causal=causal, window=window)
     return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)

@@ -22,6 +22,9 @@ KEY = jax.random.PRNGKey(42)
     ((1, 200, 200, 16), True, 64, 64, 64),      # ragged + sliding window
     ((1, 64, 256, 64), False, None, 32, 64),    # cross-attention shape
     ((2, 100, 300, 8), False, 128, 32, 128),
+    ((1, 300, 300, 64), False, None, 128, 128),  # joint-like: ragged last K tile
+    ((1, 150, 420, 32), False, None, 64, 128),  # local q slice vs global K/V
+    ((1, 1100, 1100, 16), False, None, None, None),  # mha's shape-chosen tiles
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_matches_oracle(shape, causal, window, bq, bk, dtype):

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.diffusion.config import SD3_DIT
+from repro.diffusion.config import SD3_DIT, SD35_LARGE_DIT
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.lora_matmul.kernel import lora_matmul_grouped
 from repro.kernels.quant_matmul.kernel import quant_matmul
@@ -55,17 +55,32 @@ def _compiled_hlo(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("bh,seq,head_dim,dtype", [
+# the served attention shapes, each with the tiles the kernel picks from
+# them: the compile fails where those tiles do not fit VMEM
+@pytest.mark.parametrize("bh,sq,sk,head_dim,dtype", [
     # joint text+image attention: 2 CFG rows x 24 heads, 4096 + 333 tokens
-    (2 * SD3_DIT.n_heads, SD3_DIT.tokens, SD3_DIT.head_dim, jnp.bfloat16),
+    (2 * SD3_DIT.n_heads, SD3_DIT.tokens, SD3_DIT.tokens, SD3_DIT.head_dim,
+     jnp.bfloat16),
     # the stand-in text encoder: 4 heads over text_dim 4096
-    (4, SD3_DIT.text_tokens, SD3_DIT.text_dim // 4, jnp.float32),
-], ids=["sd3_joint", "text_encoder"])
-def test_flash_attention_compiles(one_chip, bh, seq, head_dim, dtype):
+    (4, SD3_DIT.text_tokens, SD3_DIT.text_tokens, SD3_DIT.text_dim // 4,
+     jnp.float32),
+    # the backlog's batch of 8 requests, both CFG rows
+    (16 * SD3_DIT.n_heads, SD3_DIT.tokens, SD3_DIT.tokens, SD3_DIT.head_dim,
+     jnp.bfloat16),
+    (2 * SD35_LARGE_DIT.n_heads, SD35_LARGE_DIT.tokens, SD35_LARGE_DIT.tokens,
+     SD35_LARGE_DIT.head_dim, jnp.bfloat16),
+    (16 * SD35_LARGE_DIT.n_heads, SD35_LARGE_DIT.tokens,
+     SD35_LARGE_DIT.tokens, SD35_LARGE_DIT.head_dim, jnp.bfloat16),
+    # the sequence-sharded block at k=4: local queries against global K/V
+    (2 * SD3_DIT.n_heads, SD3_DIT.text_tokens + SD3_DIT.image_tokens // 4,
+     SD3_DIT.tokens, SD3_DIT.head_dim, jnp.bfloat16),
+], ids=["sd3_joint", "text_encoder", "sd3_joint_b8", "sd35l_joint",
+        "sd35l_joint_b8", "sd3_seq_sharded"])
+def test_flash_attention_compiles(one_chip, bh, sq, sk, head_dim, dtype):
     fn = functools.partial(flash_attention, causal=False, interpret=False)
-    shape = ((bh, seq, head_dim), dtype)
-    assert "tpu_custom_call" in _compiled_hlo(fn, one_chip, shape, shape,
-                                              shape)
+    q = ((bh, sq, head_dim), dtype)
+    kv = ((bh, sk, head_dim), dtype)
+    assert "tpu_custom_call" in _compiled_hlo(fn, one_chip, q, kv, kv)
 
 
 @pytest.mark.parametrize("rank_cols", [24, 256], ids=["G3r8", "G32r8"])
