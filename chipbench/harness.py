@@ -27,6 +27,7 @@ clock.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -34,7 +35,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -81,7 +82,8 @@ class Cell:
 
 def resolve(workload: str, root: Path = ROOT) -> Cell:
     """Everything ``BENCHMARK.json`` and the files it names say of one
-    cell, found by name."""
+    cell, found by name; the configuration's architecture must have its
+    reference module."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -89,6 +91,7 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
     w = cells[workload]
     centry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = json.loads((root / centry["file"]).read_text())
+    architecture(config, root)
     bench = root / "chipbench"
     traffic = json.loads((bench / "traffic" / f"{w['traffic']}.json").read_text())
     limits = json.loads((bench / "limits" / f"{workload}.json").read_text())
@@ -100,6 +103,33 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
                      else m["moves"] in names)]
     return Cell(workload, int(w["chips"]), config, traffic, limits, e2e,
                 per_layer)
+
+
+def architecture(config: Dict[str, Any], root: Path = ROOT) -> Any:
+    """The reference module of the configuration's ``architecture``,
+    ``chipbench/reference/<architecture>.py`` under ``root``: the
+    backbone's reference, counts and program fields, as the contract in
+    ``chipbench/reference/__init__.py`` says."""
+    name = config.get("architecture")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(f"configuration {config.get('name')!r} names no "
+                         f"architecture (got {name!r})")
+    path = root / "chipbench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"configuration {config.get('name')!r}: architecture "
+                         f"{name!r} has no reference module {path}")
+    return _load_module(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: Path) -> Any:
+    name = f"chipbench_architecture_{path.stem}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # a dataclass looks its module up in sys.modules while it is built
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def check_device(chips: int) -> Any:
@@ -131,36 +161,29 @@ def use_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def family_for(config: Dict[str, Any], check_published: bool = True) -> Any:
-    """The program's family, served at the configuration's sizes.  With
-    ``check_published``, every size that ``reduced`` does not name must
+def family_for(config: Dict[str, Any], check_published: bool = True,
+               root: Path = ROOT) -> Any:
+    """The program's family, served at the configuration's sizes (its
+    architecture's ``program_fields`` over the published geometry).  With
+    ``check_published``, every field that no key of ``reduced`` sets must
     equal the program's published geometry."""
     import jax.numpy as jnp
-    from repro.diffusion.config import FAMILIES, DiTConfig
+    from repro.diffusion.config import FAMILIES
 
-    from chipbench.reference.mmdit import geometry_from_config
-
-    g = geometry_from_config(config)
+    arch = architecture(config, root)
+    fields = arch.program_fields(arch.geometry_from_config(config))
     fam = FAMILIES[config["family"]]
-    dit = DiTConfig(d_model=g.d_model, n_layers=g.n_layers, n_heads=g.n_heads,
-                    d_ff=g.d_ff, text_dim=g.text_dim,
-                    latent_size=g.latent_size,
-                    latent_channels=g.latent_channels, patch=g.patch,
-                    text_tokens=g.text_tokens, dtype=getattr(jnp, g.dtype))
+    pub = fam.published
     if check_published:
-        pub = fam.published
-        reduced = {"num_layers": "n_layers"}
-        skip = {reduced[k] for k in config.get("reduced", {})}
-        for f in dataclasses.fields(DiTConfig):
-            if f.name in skip:
-                continue
-            a, b = getattr(dit, f.name), getattr(pub, f.name)
-            if f.name == "dtype":
+        skip = {arch.REDUCIBLE[k] for k in config.get("reduced", {})}
+        for name, a in fields.items():
+            b = getattr(pub, name)
+            if name == "dtype":
                 a, b = jnp.dtype(a), jnp.dtype(b)
-            if a != b:
-                raise RunFailure(f"{config['name']}: {f.name} {a} differs from "
+            if name not in skip and a != b:
+                raise RunFailure(f"{config['name']}: {name} {a} differs from "
                                  f"the program's published {b}")
-    return dataclasses.replace(fam, dit=dit)
+    return dataclasses.replace(fam, dit=dataclasses.replace(pub, **fields))
 
 
 # ------------------------------------------------------- compile counting
@@ -380,7 +403,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
 
     from chipbench import traffic, xplane
-    from chipbench.reference.mmdit import geometry_from_config
 
     counter = CompileCounter()
     mix = traffic.mix_from(cell.traffic, cell.config)
@@ -466,7 +488,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     del served, co
     gc.collect()
     t0 = time.perf_counter()
-    checks = compare(geometry_from_config(cell.config), mix, picked, control)
+    arch = architecture(cell.config)
+    checks = compare(arch, arch.geometry_from_config(cell.config), mix, picked,
+                     control)
     check_s = time.perf_counter() - t0
     return Outcome(
         setup_s=t_setup - t_process,
@@ -523,9 +547,10 @@ def rel_gap(got: np.ndarray, want: np.ndarray) -> float:
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def compare(g: Any, mix: Any, picked: Picked,
+def compare(ref: Any, g: Any, mix: Any, picked: Picked,
             control: bool = False) -> Dict[str, float]:
-    """The numbers compared with their limits, each the worst of its kind:
+    """The numbers compared with their limits, each the worst of its kind,
+    against the reference module ``ref`` of the cell's architecture:
 
     * ``text_embed_gap``: each prompt embedding of the dispatch against the
       reference encoder's;
@@ -538,7 +563,6 @@ def compare(g: Any, mix: Any, picked: Picked,
     With ``control``, the reference computed with float8 weights is put
     in the program's place, on the same inputs.
     """
-    from chipbench.reference import mmdit as ref
     from chipbench.reference import standins
 
     emb = standins.as_numpy(standins.encode(g, picked.prompts))
@@ -586,12 +610,14 @@ def correct(checks: Dict[str, float], limits: Dict[str, float]) -> bool:
 @dataclasses.dataclass
 class Readings:
     """What a per-layer metric reader may read: the traced window (open to
-    the end of the drain) and its dispatches."""
+    the end of the drain) and its dispatches, and the cell's geometry with
+    the reference module of its architecture, which counts it."""
 
     window_s: float
     dispatches: List[Dispatch]
     trace: Any
     geometry: Any
+    architecture: Any
     peaks: Dict[str, float]
     programs: Dict[str, str]
     flash_kernel: str
@@ -602,17 +628,28 @@ class Readings:
     def request_steps(self) -> int:
         return sum(d.batch_size * d.steps for d in self.segment_dispatches())
 
+    @property
+    def rows_per_step(self) -> int:
+        """Backbone rows in one request-step."""
+        return self.architecture.rows_per_step(self.geometry)
+
+    @property
+    def attention_calls(self) -> List[Tuple[float, float]]:
+        """(FLOPs, bytes) of each ``mha`` call of one backbone row-step."""
+        return self.architecture.attention_calls(self.geometry)
+
     def device(self) -> Any:
         return self.trace.devices[0] if self.trace is not None else None
 
     def flops(self) -> float:
         """Operations of every dispatch of the window, counted from
-        shapes: backbone steps (both CFG rows), prompts encoded and images
-        decoded."""
+        shapes: backbone steps (every row of each request-step), prompts
+        encoded and images decoded."""
         from chipbench import flops as F
 
         g = self.geometry
-        per = {"segment": F.request_step_flops(g),
+        per = {"segment": self.rows_per_step
+               * self.architecture.row_step_flops(g),
                "text_encoder": F.text_encoder_flops(g),
                "vae": F.vae_decode_flops(g)}
         return sum(d.batch_size * d.steps * per.get(d.model_id.split(":")[0], 0)

@@ -1,8 +1,9 @@
 """The flash-attention kernel's share of its roofline inside the
 DenoiseSegment program, in %: the least time the chip needs for the
-unpadded QK^T and PV work of every joint attention call of the window
-(the larger of FLOPs over the bf16 peak and q, k, v, o bytes over HBM
-bandwidth), over the kernel's device time (device trace)."""
+unpadded work of every attention call of the window (for each call the
+cell's architecture counts in a backbone row-step, the larger of its
+FLOPs over the bf16 peak and its q, k, v, o bytes over HBM bandwidth),
+over the kernel's device time (device trace)."""
 
 from chipbench import flops, xplane
 
@@ -15,8 +16,8 @@ def read(r):
                                        r.programs["segment"])
     if not calls:
         return None
-    g, p = r.geometry, r.peaks
-    per_call = flops.roofline_seconds(
-        flops.flash_attn_flops(g), flops.flash_attn_bytes(g),
-        p["bf16_flops_per_s"], p["hbm_bytes_per_s"])
-    return 100.0 * per_call * 2 * steps * g.n_layers / seconds
+    p = r.peaks
+    row_step = sum(flops.roofline_seconds(f, b, p["bf16_flops_per_s"],
+                                          p["hbm_bytes_per_s"])
+                   for f, b in r.attention_calls)
+    return 100.0 * row_step * r.rows_per_step * steps / seconds

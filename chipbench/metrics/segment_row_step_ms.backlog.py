@@ -1,5 +1,6 @@
-"""Device time of the DenoiseSegment program per CFG row per denoising
-step, in ms (device trace; steps from the dispatch log)."""
+"""Device time of the DenoiseSegment program per backbone row per
+denoising step, in ms (device trace; steps from the dispatch log, rows
+per step from the cell's architecture)."""
 
 from chipbench import xplane
 
@@ -9,4 +10,4 @@ def read(r):
     if dev is None or not steps:
         return None
     seconds, runs = xplane.program_seconds(dev, r.programs["segment"])
-    return 1e3 * seconds / (2 * steps) if runs else None
+    return 1e3 * seconds / (r.rows_per_step * steps) if runs else None

@@ -25,6 +25,10 @@ Weights are rebuilt from ``PRNGKey(crc32(model_id) % 2**31)`` with the key
 tree and scales of the program's initializer, rounded to the served dtype
 (bfloat16), and then used in float32.  One layer's weights exist at a time,
 so the reference fits beside nothing else on one chip at every width.
+
+The module meets the architecture contract of ``chipbench/reference``: it
+also counts the backbone's operations and bytes from its shapes, beside
+the equations they count.
 """
 
 from __future__ import annotations
@@ -33,7 +37,7 @@ import dataclasses
 import math
 import zlib
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -313,3 +317,64 @@ def sample(g: Geometry, lat: jax.Array, emb: jax.Array, steps: int,
         lat = guided_step(g, lat, emb, float(sched[i]), float(sched[i + 1]),
                           guidance, fp8)
     return lat
+
+
+# ------------------------------------------------------------------ counts
+# counted as ``chipbench/flops.py`` says: a multiply-add is 2 FLOPs, and
+# elementwise work is left out
+
+def rows_per_step(g: Geometry) -> int:
+    """Both rows of classifier-free guidance."""
+    return 2
+
+
+def layer_flops(g: Geometry) -> Dict[str, float]:
+    """One MMDiT block for one CFG row: the dense projections of the
+    image and text streams (q, k, v, o and the two MLP matmuls) and the
+    joint attention (QK^T and PV over all tokens)."""
+    d, ff = g.d_model, g.d_ff
+    per_token = 2 * (4 * d * d + 2 * d * ff)
+    ada = 2 * 2 * d * 6 * d                 # both streams, once per row
+    return {"image": per_token * g.image_tokens,
+            "text": per_token * g.text_tokens,
+            "attention": flash_attn_flops(g),
+            "ada": ada}
+
+
+def flash_attn_flops(g: Geometry) -> float:
+    """QK^T and PV of one joint attention call for one row, unpadded."""
+    return 4.0 * g.tokens * g.tokens * g.d_model
+
+
+def flash_attn_bytes(g: Geometry, itemsize: int = 2) -> float:
+    """Least HBM traffic of that call: q, k, v read once, o written once."""
+    return 4.0 * g.tokens * g.d_model * itemsize
+
+
+def row_step_flops(g: Geometry) -> float:
+    """One backbone forward of one CFG row: every block, the patch,
+    text and timestep embeddings and the final adaLN and head."""
+    layer = sum(layer_flops(g).values())
+    d = g.d_model
+    embed = 2 * (g.image_tokens * g.in_dim * d + g.text_tokens * g.text_dim * d
+                 + 256 * d + d * d)
+    head = 2 * (d * 2 * d + g.image_tokens * d * g.in_dim)
+    return g.n_layers * layer + embed + head
+
+
+def attention_calls(g: Geometry) -> List[Tuple[float, float]]:
+    """One joint attention call per block."""
+    return [(flash_attn_flops(g), flash_attn_bytes(g))] * g.n_layers
+
+
+# ------------------------------------------------- the program's config
+
+REDUCIBLE = {"num_layers": "n_layers"}
+
+
+def program_fields(g: Geometry) -> Dict[str, Any]:
+    """The program's ``DiTConfig`` at these sizes."""
+    return dict(d_model=g.d_model, n_layers=g.n_layers, n_heads=g.n_heads,
+                d_ff=g.d_ff, text_dim=g.text_dim, latent_size=g.latent_size,
+                latent_channels=g.latent_channels, patch=g.patch,
+                text_tokens=g.text_tokens, dtype=getattr(jnp, g.dtype))

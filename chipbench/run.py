@@ -48,11 +48,11 @@ def per_layer(cell, out, harness) -> dict:
     import jax
 
     from chipbench.peaks import peaks_for
-    from chipbench.reference.mmdit import geometry_from_config
 
+    arch = harness.architecture(cell.config)
     readings = harness.Readings(
         window_s=out.traced_s, dispatches=out.traced, trace=out.trace,
-        geometry=geometry_from_config(cell.config),
+        geometry=arch.geometry_from_config(cell.config), architecture=arch,
         peaks=peaks_for(jax.devices()[0].device_kind),
         programs=harness.PROGRAMS, flash_kernel=harness.FLASH_KERNEL)
     metrics = {}

@@ -47,6 +47,21 @@ def test_sound_program_is_correct(traffic):
     assert harness.correct(out.checks, TOY_LIMITS), out.checks
 
 
+def test_check_runs_the_architecture_the_config_names(monkeypatch):
+    arch = harness.architecture(toy_cell("backlog").config)
+    assert Path(arch.__file__) == BENCH / "reference" / "mmdit.py"
+    real, calls = arch.sample, []
+
+    def counted(*args, **kwargs):
+        calls.append(args[5:7])             # the chain's start and stop
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(arch, "sample", counted)
+    out = run("backlog", seed=2**33 + 1)
+    assert len(calls) == 2                  # one per followed request
+    assert harness.correct(out.checks, TOY_LIMITS), out.checks
+
+
 def test_control_is_not_correct():
     from repro.nn.layers import set_quant_mode
 

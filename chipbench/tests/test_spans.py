@@ -21,7 +21,8 @@ def trace(host, ops=((0.0, 1.0), (2.0, 3.0))):
 def readings(t, segments=1):
     seg = [harness.Dispatch("segment:sd3", 8, 1)] * segments
     return harness.Readings(window_s=3.0, dispatches=seg, trace=t,
-                            geometry=None, peaks={}, programs=harness.PROGRAMS,
+                            geometry=None, architecture=None, peaks={},
+                            programs=harness.PROGRAMS,
                             flash_kernel=harness.FLASH_KERNEL)
 
 
