@@ -6,7 +6,8 @@ Each family carries:
   checkpoints, used with ``published`` by the analytic latency profiles,
   the monolithic baselines, and the roofline analysis;
 * ``published`` — the backbone geometry of the published checkpoint: an
-  MMDiT's full :class:`DiTConfig` in bf16, which
+  MMDiT's full :class:`DiTConfig` or a FLUX backbone's
+  :class:`FluxConfig`, in bf16, which
   :meth:`DiffusionFamily.at_published_width` swaps in for ``dit`` to serve
   the family at its real width, or a :class:`UNetGeometry` for a UNet
   (SDXL), which only the cost model reads;
@@ -17,7 +18,8 @@ Each family carries:
 from __future__ import annotations
 
 import dataclasses
-from typing import Union
+import math
+from typing import Tuple, Union
 
 import jax.numpy as jnp
 
@@ -49,6 +51,90 @@ class DiTConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    architecture = "mmdit"
+    schedule_shift = 1.0
+
+    @property
+    def adapter_layers(self) -> int:
+        """Blocks whose image stream carries LoRA targets."""
+        return self.n_layers
+
+
+@dataclasses.dataclass(frozen=True)
+class FluxConfig:
+    """Architecture of a FLUX.1 backbone (``FluxTransformer2DModel``):
+    ``n_double`` two-stream blocks, then ``n_single`` single-stream
+    parallel blocks over the joined [text; image] sequence, with 3-axis
+    RoPE on q and k, QK RMSNorm, and the time, guidance and pooled-text
+    embedders feeding every block's modulation."""
+
+    d_model: int
+    n_double: int
+    n_single: int
+    n_heads: int
+    d_ff: int
+    text_dim: int
+    pooled_dim: int           # width of the pooled text vector (CLIP-L)
+    latent_size: int          # latent spatial resolution (square)
+    latent_channels: int
+    patch: int
+    text_tokens: int
+    rope_axes: Tuple[int, ...]     # head_dim split over (text, row, col)
+    rope_theta: float
+    guidance_embed: bool      # guidance is a backbone input (dev), not CFG
+    time_shift: bool          # schedule shifted by exp(mu(image tokens))
+    dtype: object = jnp.float32
+
+    architecture = "flux"
+
+    @property
+    def n_layers(self) -> int:
+        return self.n_double + self.n_single
+
+    @property
+    def adapter_layers(self) -> int:
+        return self.n_double
+
+    @property
+    def image_tokens(self) -> int:
+        return (self.latent_size // self.patch) ** 2
+
+    @property
+    def tokens(self) -> int:
+        return self.image_tokens + self.text_tokens
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def in_dim(self) -> int:
+        return self.patch * self.patch * self.latent_channels
+
+    @property
+    def schedule_shift(self) -> float:
+        """exp(mu) of BFL's ``get_schedule``: mu interpolated linearly in
+        the image token count from 0.5 at 256 tokens to 1.15 at 4096;
+        1 (no shift) for a model sampled unshifted (schnell)."""
+        if not self.time_shift:
+            return 1.0
+        mu = 0.5 + (1.15 - 0.5) / (4096 - 256) * (self.image_tokens - 256)
+        return math.exp(mu)
+
+    @property
+    def n_params(self) -> int:
+        """Parameters, counted from the shapes of ``init_flux``."""
+        d, ff, hd = self.d_model, self.d_ff, self.head_dim
+        lin = lambda i, o: i * o + o
+        double = 2 * (lin(d, 6 * d) + 3 * lin(d, d) + 2 * hd + lin(d, d)
+                      + lin(d, ff) + lin(ff, d))
+        single = lin(d, 3 * d) + lin(d, 3 * d + ff) + 2 * hd + lin(d + ff, d)
+        mlp = lambda i: lin(i, d) + lin(d, d)
+        outer = (lin(self.in_dim, d) + lin(self.text_dim, d) + mlp(256)
+                 + mlp(self.pooled_dim) + lin(d, 2 * d) + lin(d, self.in_dim)
+                 + (mlp(256) if self.guidance_embed else 0))
+        return self.n_double * double + self.n_single * single + outer
+
 
 @dataclasses.dataclass(frozen=True)
 class UNetGeometry:
@@ -61,10 +147,20 @@ class UNetGeometry:
     image_tokens: int
     text_tokens: int
 
+    @property
+    def adapter_layers(self) -> int:
+        return self.n_layers
+
 
 _TOY = DiTConfig(
     d_model=64, n_layers=2, n_heads=4, d_ff=256, text_dim=64,
     latent_size=16, latent_channels=4, patch=2, text_tokens=8,
+)
+_TOY_FLUX = FluxConfig(
+    d_model=64, n_double=1, n_single=2, n_heads=4, d_ff=256, text_dim=64,
+    pooled_dim=16, latent_size=16, latent_channels=4, patch=2,
+    text_tokens=8, rope_axes=(4, 6, 6), rope_theta=10000.0,
+    guidance_embed=True, time_shift=True,
 )
 
 
@@ -79,8 +175,8 @@ class DiffusionFamily:
     controlnet_params: float
     denoise_steps: int
     uses_cfg: bool                # classifier-free guidance (2 passes/step)
-    published: Union[DiTConfig, UNetGeometry]   # published backbone geometry
-    dit: DiTConfig = _TOY         # executable backbone config
+    published: Union[DiTConfig, FluxConfig, UNetGeometry]
+    dit: Union[DiTConfig, FluxConfig] = _TOY     # executable backbone config
 
     @property
     def image_tokens(self) -> int:    # 1024px -> 4096 (patch-2 on /8 VAE)
@@ -93,18 +189,30 @@ class DiffusionFamily:
     def at_published_width(self) -> "DiffusionFamily":
         """This family with its published backbone geometry as the
         executable config (same name, so workflows and model ids match)."""
-        if not isinstance(self.published, DiTConfig):
-            raise ValueError(f"{self.name}: no published MMDiT geometry")
+        if not isinstance(self.published, (DiTConfig, FluxConfig)):
+            raise ValueError(f"{self.name}: no published backbone geometry")
         return dataclasses.replace(self, dit=self.published)
 
     @property
     def cfg_factor(self) -> float:
         return 2.0 if self.uses_cfg else 1.0
 
+    def served_backbone_params(self) -> float:
+        """Parameters of the backbone as served: a FLUX pipeline stage (the
+        published geometry with blocks cut, as a one-chip cell serves it)
+        holds its own count; otherwise the published model's, which the
+        CPU-sized toy stands in for."""
+        dit, pub = self.dit, self.published
+        if isinstance(dit, FluxConfig) and dataclasses.replace(
+                dit, n_double=pub.n_double, n_single=pub.n_single) == pub:
+            return float(dit.n_params)
+        return self.backbone_params
+
     def backbone_step_flops(self) -> float:
         """FLOPs of ONE denoising step per request (incl. CFG passes)."""
         tokens = self.image_tokens + self.text_tokens
-        return 2.0 * self.backbone_params * tokens * self.cfg_factor
+        return (2.0 * self.served_backbone_params() * tokens
+                * self.cfg_factor)
 
     def controlnet_step_flops(self) -> float:
         tokens = self.image_tokens + self.text_tokens
@@ -119,7 +227,7 @@ class DiffusionFamily:
 
     # ------------------------------------------------------------- bytes
     def backbone_bytes(self) -> float:
-        return self.backbone_params * 2.0          # fp16/bf16 weights
+        return self.served_backbone_params() * 2.0     # bf16 weights
 
     def text_encoder_bytes(self) -> float:
         return self.text_encoder_params * 2.0
@@ -146,8 +254,7 @@ class DiffusionFamily:
 
 
 # Published backbone geometries in bf16: 1024px images (128x128x16
-# latents, patch 2 -> 4096 image tokens).  The two-stream MMDiT block
-# slightly over-parameterizes Flux's mixed joint/single-stream stack.
+# latents, patch 2 -> 4096 image tokens).
 SD3_DIT = DiTConfig(
     d_model=1536, n_layers=24, n_heads=24, d_ff=6144, text_dim=4096,
     latent_size=128, latent_channels=16, patch=2, text_tokens=333,
@@ -158,11 +265,18 @@ SD35_LARGE_DIT = DiTConfig(
     latent_size=128, latent_channels=16, patch=2, text_tokens=333,
     dtype=jnp.bfloat16,
 )
-FLUX_DIT = DiTConfig(
-    d_model=3072, n_layers=57, n_heads=24, d_ff=12288, text_dim=4096,
-    latent_size=128, latent_channels=16, patch=2, text_tokens=512,
-    dtype=jnp.bfloat16,
+# FLUX.1-dev's transformer/config.json (19 double- and 38 single-stream
+# blocks, 24 heads of 128, axes_dims_rope [16, 56, 56], guidance_embeds)
+# and its pipeline defaults (512 T5 tokens, a dynamically shifted schedule)
+FLUX_DEV_DIT = FluxConfig(
+    d_model=3072, n_double=19, n_single=38, n_heads=24, d_ff=12288,
+    text_dim=4096, pooled_dim=768, latent_size=128, latent_channels=16,
+    patch=2, text_tokens=512, rope_axes=(16, 56, 56), rope_theta=10000.0,
+    guidance_embed=True, time_shift=True, dtype=jnp.bfloat16,
 )
+# FLUX.1-schnell: the same blocks, no guidance embedder, unshifted schedule
+FLUX_SCHNELL_DIT = dataclasses.replace(
+    FLUX_DEV_DIT, guidance_embed=False, time_shift=False)
 
 SD3 = DiffusionFamily(
     name="sd3",
@@ -188,24 +302,27 @@ SD35_LARGE = DiffusionFamily(
 
 FLUX_DEV = DiffusionFamily(
     name="flux-dev",
-    backbone_params=12.0e9,
+    backbone_params=float(FLUX_DEV_DIT.n_params),
     text_encoder_params=4.9e9,      # CLIP-L + T5-XXL
     vae_params=8.4e7,
     controlnet_params=0.72e9,       # ~6% of base (paper §7.3)
     denoise_steps=28,
     uses_cfg=False,                 # guidance-distilled
-    published=FLUX_DIT,
+    published=FLUX_DEV_DIT,
+    dit=_TOY_FLUX,
 )
 
 FLUX_SCHNELL = DiffusionFamily(
     name="flux-schnell",
-    backbone_params=12.0e9,
+    backbone_params=float(FLUX_SCHNELL_DIT.n_params),
     text_encoder_params=4.9e9,
     vae_params=8.4e7,
     controlnet_params=0.72e9,
     denoise_steps=4,                # timestep-distilled
     uses_cfg=False,
-    published=FLUX_DIT,
+    published=FLUX_SCHNELL_DIT,
+    dit=dataclasses.replace(_TOY_FLUX, guidance_embed=False,
+                            time_shift=False),
 )
 
 FAMILIES = {f.name: f for f in (SD3, SD35_LARGE, FLUX_DEV, FLUX_SCHNELL)}

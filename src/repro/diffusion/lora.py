@@ -1,6 +1,7 @@
 """LoRA adapters for the MMDiT backbone (§2.1, weight-patching adapters).
 
-A LoRA targets the image-stream attention projections of every layer:
+A LoRA targets the image-stream attention projections of every two-stream
+block (every MMDiT block; a FLUX backbone's double blocks):
 ``W' = W + scale * A @ B`` with ``A: [L, d, r]``, ``B: [L, r, d]``.
 
 Two application modes:
@@ -20,7 +21,6 @@ from typing import Any, Dict, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.diffusion.config import DiTConfig
 from repro.kernels.quant_matmul.ops import (
     dequantize_weight,
     is_quantized,
@@ -78,17 +78,17 @@ def quantize_text_lora(tl: Params) -> Params:
             for k, v in tl.items()}
 
 
-def init_lora(key: jax.Array, cfg: DiTConfig, rank: int = 8,
+def init_lora(key: jax.Array, cfg: Any, rank: int = 8,
               scale: float = 1.0) -> Params:
-    d = cfg.d_model
+    d, n = cfg.d_model, cfg.adapter_layers
     ks = split(key, 2 * len(TARGETS))
     p: Params = {"scale": jnp.asarray(scale, cfg.dtype)}
     for i, t in enumerate(TARGETS):
         p[f"{t}_a"] = (
-            jax.random.normal(ks[2 * i], (cfg.n_layers, d, rank), dtype=jnp.float32)
+            jax.random.normal(ks[2 * i], (n, d, rank), dtype=jnp.float32)
             * (1.0 / jnp.sqrt(d))
         ).astype(cfg.dtype)
-        p[f"{t}_b"] = jnp.zeros((cfg.n_layers, rank, d), cfg.dtype)
+        p[f"{t}_b"] = jnp.zeros((n, rank, d), cfg.dtype)
     return p
 
 

@@ -292,9 +292,12 @@ def _mmdit_block_seq(
     return x, c
 
 
-def seq_shard_divisor(cfg: DiTConfig, k: int) -> bool:
-    """Can the latent's patch-row grid split evenly across k devices?"""
-    return (cfg.latent_size // cfg.patch) % k == 0
+def seq_shard_divisor(cfg: Any, k: int) -> bool:
+    """Can the latent's patch-row grid split evenly across k devices for
+    the sequence-sharded MMDiT forward?  Never for another architecture:
+    FLUX has no sequence-sharded forward."""
+    return (cfg.architecture == "mmdit"
+            and (cfg.latent_size // cfg.patch) % k == 0)
 
 
 def mmdit_apply_seq_sharded(

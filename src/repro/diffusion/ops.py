@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.model import Model, ModelCost
 from repro.core.types import Image, TensorType
-from repro.diffusion.config import DiffusionFamily, DiTConfig
+from repro.diffusion.config import DiffusionFamily, DiTConfig, FluxConfig
 from repro.nn.layers import shard_map_compat
 from repro.diffusion.encoders import (
     init_text_encoder,
@@ -38,6 +38,7 @@ from repro.diffusion.encoders import (
     vae_decode,
     vae_encode,
 )
+from repro.diffusion.flux import flux_apply, init_flux, quantize_flux_params
 from repro.diffusion.lora import (
     fold_lora,
     fold_text_lora,
@@ -66,6 +67,10 @@ from repro.diffusion.sampler import (
 )
 
 _TOY_VOCAB = 512
+
+
+def _is_flux(cfg: Any) -> bool:
+    return cfg.architecture == "flux"
 
 
 def _split_rows(val: jnp.ndarray, sizes: List[int], axis: int = 0) -> List[jnp.ndarray]:
@@ -298,8 +303,10 @@ class DiffusionBackbone(Model):
 
     def load(self, device: Any = None) -> Dict[str, Any]:
         cfg = self.family.dit
-        params = quantize_mmdit_params(init_mmdit(
-            jax.random.PRNGKey(stable_hash(self.model_id) % 2**31), cfg))
+        key = jax.random.PRNGKey(stable_hash(self.model_id) % 2**31)
+        if _is_flux(cfg):
+            return self._load_flux(key, cfg)
+        params = quantize_mmdit_params(init_mmdit(key, cfg))
         apply = jax.jit(
             lambda p, lat, t, emb, res: mmdit_apply(p, cfg, lat, t, emb, res)
         )
@@ -331,6 +338,20 @@ class DiffusionBackbone(Model):
                 "forward": jax.jit(_forward),
                 "forward_ml": jax.jit(_forward_ml), "cfg": cfg}
 
+    @staticmethod
+    def _load_flux(key: jax.Array, cfg: FluxConfig) -> Dict[str, Any]:
+        """A guidance-distilled FLUX backbone: the guidance vector is an
+        input of the forward (one row per request, no CFG) and there is
+        no ControlNet residual input (``res`` must be None)."""
+        params = quantize_flux_params(init_flux(key, cfg))
+        forward = jax.jit(
+            lambda p, lat, t, emb, res, g: flux_apply(p, cfg, lat, t, emb, g))
+        forward_ml = jax.jit(
+            lambda p, lat, t, emb, res, g, stack, idx: flux_apply(
+                p, cfg, lat, t, emb, g, lora_stack=stack, lora_idx=idx))
+        return {"params": params, "apply": forward, "forward": forward,
+                "forward_ml": forward_ml, "cfg": cfg}
+
     def fold_patches(
         self,
         components: Dict[str, Any],
@@ -345,7 +366,9 @@ class DiffusionBackbone(Model):
         params = components["params"]
         for pc in patch_components:
             params = fold_lora(params, pc["lora"])
-        return {**components, "params": quantize_mmdit_params(params)}
+        quantize = (quantize_flux_params if _is_flux(components["cfg"])
+                    else quantize_mmdit_params)
+        return {**components, "params": quantize(params)}
 
     def _velocity(
         self,
@@ -368,9 +391,14 @@ class DiffusionBackbone(Model):
             return fused_cfg_velocity(apply, params, lat, t, emb, g, res)
         return apply(params, lat, t, emb, res)
 
-    def _materialize_residuals(self, cfg: DiTConfig, kw: Dict[str, Any],
-                               lat: jnp.ndarray) -> jnp.ndarray:
+    def _materialize_residuals(self, cfg: Any, kw: Dict[str, Any],
+                               lat: jnp.ndarray) -> Optional[jnp.ndarray]:
         res = kw.get("controlnet_residuals")
+        if _is_flux(cfg):
+            if res is not None:
+                raise ValueError(f"{self.model_id}: the FLUX backbone takes "
+                                 "no ControlNet residuals")
+            return None
         if res is None:
             res = jnp.zeros(
                 (cfg.n_layers, lat.shape[0], cfg.image_tokens, cfg.d_model),
@@ -463,10 +491,9 @@ class DiffusionBackbone(Model):
         t = jnp.asarray(np.repeat(
             np.asarray([float(kw["t"]) for kw in batch_kwargs], np.float32),
             sizes))
-        res = jnp.concatenate([
-            self._materialize_residuals(cfg, kw, l)
-            for kw, l in zip(batch_kwargs, lats)
-        ], axis=1)
+        res = [self._materialize_residuals(cfg, kw, l)
+               for kw, l in zip(batch_kwargs, lats)]
+        res = None if res[0] is None else jnp.concatenate(res, axis=1)
         guidance = np.repeat(
             np.asarray([float(kw.get("guidance", 4.5))
                         for kw in batch_kwargs], np.float32), sizes)
@@ -507,6 +534,9 @@ class DiffusionBackbone(Model):
             return None
         lat, emb, t, res, guidance, sizes = stacked
         params = model_components["params"]
+        if _is_flux(cfg):
+            return self._execute_flux_sharded(
+                model_components, lat, t, emb, guidance, sizes, mesh)
         uses_cfg = self.family.uses_cfg
         b = int(lat.shape[0])
         if uses_cfg:     # fold CFG onto the batch axis before sharding
@@ -553,6 +583,31 @@ class DiffusionBackbone(Model):
             v = v2
         return [{"velocity": chunk} for chunk in _split_rows(v, sizes)]
 
+    @staticmethod
+    def _execute_flux_sharded(comps: Dict[str, Any], lat, t, emb, guidance,
+                              sizes: List[int], mesh: Any
+                              ) -> Optional[List[Dict[str, Any]]]:
+        """Rows of a FLUX batch sharded across the submesh, each device
+        running ``flux_apply`` on its rows.  There is no sequence-sharded
+        FLUX forward: a row count that does not divide k declines."""
+        k, axis = mesh.size, mesh.axis_names[0]
+        if int(lat.shape[0]) % k:
+            return None
+        cfg = comps["cfg"]
+        cache = _mesh_fn_cache(comps)
+        key = ("dp", mesh)
+        if key not in cache:
+            cache[key] = jax.jit(shard_map_compat(
+                lambda p, l, tt, e, g: flux_apply(p, cfg, l, tt, e, g),
+                mesh=mesh,
+                in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
+                out_specs=P(axis),
+            ))
+        v = cache[key](comps["params"], _mesh_put(lat, mesh, axis),
+                       _mesh_put(t, mesh, axis), _mesh_put(emb, mesh, axis),
+                       _mesh_put(jnp.asarray(guidance), mesh, axis))
+        return [{"velocity": chunk} for chunk in _split_rows(v, sizes)]
+
     def cost(self) -> ModelCost:
         f = self.family
         g = f.published
@@ -571,8 +626,9 @@ class DiffusionBackbone(Model):
             # targets × n_layers, two skinny matmuls per row per rank;
             # per-adapter HBM traffic is the bf16 A/B factor stream
             lora_rank=8,
-            lora_flops_per_rank=16.0 * g.n_layers * f.image_tokens * g.d_model,
-            lora_bytes_per_adapter=16.0 * g.n_layers * g.d_model * 8,
+            lora_flops_per_rank=16.0 * g.adapter_layers * f.image_tokens
+            * g.d_model,
+            lora_bytes_per_adapter=16.0 * g.adapter_layers * g.d_model * 8,
             # stream projections quantize (REPRO_QUANT): the roofline
             # prices int8 forwards at the doubled MXU issue rate and the
             # halved weight stream
@@ -603,6 +659,11 @@ class ControlNet(Model):
 
     def load(self, device: Any = None) -> Dict[str, Any]:
         cfg = self.family.dit
+        if _is_flux(cfg):
+            raise NotImplementedError(
+                f"{self.model_id}: no FLUX ControlNet branch (the program's "
+                "ControlNet is a copy of MMDiT blocks, and the FLUX "
+                "backbone has no residual input)")
         params = quantize_mmdit_params(init_controlnet(
             jax.random.PRNGKey(stable_hash(self.model_id) % 2**31), cfg
         ))
@@ -998,6 +1059,7 @@ class DenoiseSegment(Model):
         cfg = self.family.dit
         uses_cfg = self.family.uses_cfg
         n_cns = len(self.cns)
+        flux = _is_flux(cfg)
 
         def run(pb, pcns, lat, emb, cond, t_mid, t_cur, t_next, guidance,
                 stack=None, idx=None):
@@ -1012,8 +1074,11 @@ class DenoiseSegment(Model):
                                        lora_idx=idx2 if uses_cfg else idx)
                 return mmdit_apply(p, cfg, l, tt, e, r)
 
-            def body(lat, xs):
-                t, tc, tn = xs
+            def velocity(lat, t):
+                if flux:
+                    # guidance is an input of the backbone: one row each
+                    return flux_apply(pb, cfg, lat, t, emb, guidance,
+                                      lora_stack=stack, lora_idx=idx)
                 if n_cns:
                     res = None
                     for pcn in pcns:
@@ -1024,10 +1089,13 @@ class DenoiseSegment(Model):
                         (cfg.n_layers, lat.shape[0], cfg.image_tokens,
                          cfg.d_model), cfg.dtype)
                 if uses_cfg:
-                    v = fused_cfg_velocity(
+                    return fused_cfg_velocity(
                         bb_apply, pb, lat, t, emb, guidance, res)
-                else:
-                    v = bb_apply(pb, lat, t, emb, res)
+                return bb_apply(pb, lat, t, emb, res)
+
+            def body(lat, xs):
+                t, tc, tn = xs
+                v = velocity(lat, t)
                 dt = (tn - tc).astype(lat.dtype)
                 dt = dt.reshape((lat.shape[0],) + (1,) * (lat.ndim - 1))
                 return lat + dt * v, None
@@ -1218,6 +1286,8 @@ class DenoiseSegment(Model):
 
     def _make_sharded_scan(self, mesh: Any) -> Any:
         cfg = self.family.dit
+        if _is_flux(cfg):
+            return self._make_flux_sharded_scan(mesh)
         uses_cfg = self.family.uses_cfg
         n_cns = len(self.cns)
         k = mesh.size
@@ -1283,6 +1353,33 @@ class DenoiseSegment(Model):
                     v = cfg_combine(v_u, v_c, g)
                 else:
                     v = v2
+                dt = (tn - tc).astype(lat.dtype)
+                dt = dt.reshape((lat.shape[0],) + (1,) * (lat.ndim - 1))
+                return lat + dt * v, None
+
+            lat, _ = jax.lax.scan(body, lat, (t_mid, t_cur, t_next))
+            return lat
+
+        return run
+
+    def _make_flux_sharded_scan(self, mesh: Any) -> Any:
+        """FLUX rows sharded across the submesh inside the scan, each
+        device running ``flux_apply`` on its rows (``execute_batch_sharded``
+        declines a row count that does not divide k: there is no
+        sequence-sharded FLUX forward)."""
+        cfg = self.family.dit
+        axis = mesh.axis_names[0]
+        bb_sharded = shard_map_compat(
+            lambda p, l, tt, e, g: flux_apply(p, cfg, l, tt, e, g),
+            mesh=mesh,
+            in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
+            out_specs=P(axis),
+        )
+
+        def run(pb, pcns, lat, emb, cond, t_mid, t_cur, t_next, guidance):
+            def body(lat, xs):
+                t, tc, tn = xs
+                v = bb_sharded(pb, lat, t, emb, guidance)
                 dt = (tn - tc).astype(lat.dtype)
                 dt = dt.reshape((lat.shape[0],) + (1,) * (lat.ndim - 1))
                 return lat + dt * v, None

@@ -46,7 +46,8 @@ class ModelSet:
 
 def _denoising_loop(ms: ModelSet, wf, lat, emb, steps: int, guidance: float,
                     controlnets: List[Model], cond_lat) -> Any:
-    sched = [float(x) for x in flow_schedule(steps)]
+    sched = [float(x) for x in flow_schedule(steps,
+                                             ms.family.dit.schedule_shift)]
     for i in range(steps):
         t_cur, t_next = sched[i], sched[i + 1]
         res = None

@@ -5,12 +5,14 @@ if __name__ == "__main__":
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
-"""Dry-run for the paper's OWN models: one CFG denoising step of the
-real-scale MMDiT backbone on the production mesh.
+"""Dry-run for the paper's OWN models: one denoising step of the
+real-scale backbone (MMDiT with CFG, or FLUX with embedded guidance) on
+the production mesh.
 
 Latent (CFG) parallelism appears here as the batch dimension carrying
 both guidance branches (2B rows over the ``data`` axis — the general
-form of the paper's 2-GPU split), with tensor parallelism over ``model``.
+form of the paper's 2-GPU split), with tensor parallelism over ``model``;
+a FLUX step has one row per request.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun_diffusion --family sd3
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.diffusion.config import FAMILIES
+from repro.diffusion.flux import flux_apply, init_flux
 from repro.diffusion.mmdit import init_mmdit, mmdit_apply
 from repro.launch.dryrun import analyze
 from repro.launch.mesh import make_production_mesh
@@ -52,15 +55,17 @@ def _specs(params: Any) -> Any:
 
 def build(family: str, batch: int = 8, mesh=None):
     cfg = REAL[family]
+    flux = cfg.architecture == "flux"
+    init = init_flux if flux else init_mmdit
     mesh = mesh or make_production_mesh()
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     dp = dp if len(dp) > 1 else dp[0]
     params_shape = jax.eval_shape(
-        lambda k: init_mmdit(k, cfg), jax.random.PRNGKey(0))
+        lambda k: init(k, cfg), jax.random.PRNGKey(0))
     pspecs = _specs(params_shape)
     named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                    is_leaf=lambda x: isinstance(x, P))
-    b2 = batch * 2                        # CFG: cond + uncond rows
+    b2 = batch * (1 if flux else 2)       # CFG: cond + uncond rows
     args = (
         jax.ShapeDtypeStruct(
             (b2, cfg.latent_size, cfg.latent_size, cfg.latent_channels),
@@ -73,6 +78,9 @@ def build(family: str, batch: int = 8, mesh=None):
                 NamedSharding(mesh, P(dp, None, None)))
 
     def denoise_step(params, latents, t, text_emb):
+        if flux:     # guidance rides in as an input, here 3.5 per row
+            return flux_apply(params, cfg, latents, t, text_emb,
+                              jnp.full_like(t, 3.5))
         return mmdit_apply(params, cfg, latents, t, text_emb)
 
     jitted = jax.jit(
@@ -82,7 +90,8 @@ def build(family: str, batch: int = 8, mesh=None):
     )
     lowered = jitted.lower(params_shape, *args)
     n_params = sum(x.size for x in jax.tree.leaves(params_shape))
-    meta = {"arch": f"diffusion:{family}", "shape": f"denoise_b{batch}_cfg",
+    meta = {"arch": f"diffusion:{family}",
+            "shape": f"denoise_b{batch}" + ("" if flux else "_cfg"),
             "mesh": "x".join(map(str, mesh.devices.shape)), "kind": "prefill",
             "params": float(n_params), "active_params": float(n_params)}
     return lowered, meta

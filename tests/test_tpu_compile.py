@@ -1,5 +1,5 @@
-"""The served path's Pallas kernels compile for a TPU v5e at sd3's
-published widths.
+"""The served path's Pallas kernels compile for a TPU v5e at the served
+families' published widths.
 
 Nothing runs: each case lowers the kernel with ``interpret=False`` for a
 described (not attached) v5e chip and asserts that the compiled program
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.diffusion.config import SD3_DIT, SD35_LARGE_DIT
+from repro.diffusion.config import FLUX_DEV_DIT, SD3_DIT, SD35_LARGE_DIT
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.lora_matmul.kernel import lora_matmul_grouped
 from repro.kernels.quant_matmul.kernel import quant_matmul
@@ -74,8 +74,12 @@ def _compiled_hlo(fn, one_chip, *shapes):
     # the sequence-sharded block at k=4: local queries against global K/V
     (2 * SD3_DIT.n_heads, SD3_DIT.text_tokens + SD3_DIT.image_tokens // 4,
      SD3_DIT.tokens, SD3_DIT.head_dim, jnp.bfloat16),
+    # FLUX's joint attention: 8 rows (no CFG row) x 24 heads of 128 over
+    # 4096 + 512 tokens, 768 x 768 tiles
+    (8 * FLUX_DEV_DIT.n_heads, FLUX_DEV_DIT.tokens, FLUX_DEV_DIT.tokens,
+     FLUX_DEV_DIT.head_dim, jnp.bfloat16),
 ], ids=["sd3_joint", "text_encoder", "sd3_joint_b8", "sd35l_joint",
-        "sd35l_joint_b8", "sd3_seq_sharded"])
+        "sd35l_joint_b8", "sd3_seq_sharded", "flux_joint_b8"])
 def test_flash_attention_compiles(one_chip, bh, sq, sk, head_dim, dtype):
     fn = functools.partial(flash_attention, causal=False, interpret=False)
     q = ((bh, sq, head_dim), dtype)
