@@ -12,7 +12,7 @@ that the compiled backbone step holds the Pallas kernel
 (``tpu_custom_call``), that one backbone step agrees with the same step on
 the reference attention at highest matmul precision, and that the flash
 kernel alone agrees with the reference at the joint sequence (4429
-tokens, a padded last block).
+tokens, a padded last block), at head_dim 64 and at 128.
 
 ``--chips 4`` runs only adaptive model parallelism: a published-width
 ``DenoiseSegment`` batch dispatched at k=4 through ``ShardedBackend``, with
@@ -184,16 +184,16 @@ def backbone_step_check(comps: Dict[str, Any], cfg: Any) -> Dict[str, Any]:
             "finite": bool(jnp.isfinite(served).all())}
 
 
-def attention_check(cfg: Any, dtype: Any) -> float:
+def attention_check(cfg: Any, dtype: Any, head_dim: int) -> float:
     """The flash kernel against ``mha(..., use_kernel=False)`` at the
-    backbone's joint sequence and heads, both under highest precision,
-    which Mosaic honours: what is left is f32 summation noise, far below
-    a padding or a precision fault."""
+    backbone's joint sequence and heads, heads of ``head_dim``, both
+    under highest precision, which Mosaic honours: what is left is f32
+    summation noise, far below a padding or a precision fault."""
     import jax
 
     from repro.kernels.flash_attention.ops import mha
 
-    shape = (1, cfg.tokens, cfg.n_heads, cfg.head_dim)
+    shape = (1, cfg.tokens, cfg.n_heads, head_dim)
     q, k, v = (jax.random.normal(key, shape).astype(dtype)
                for key in jax.random.split(jax.random.PRNGKey(SEED + 2), 3))
     with jax.default_matmul_precision("highest"):
@@ -255,14 +255,17 @@ def one_chip(fam: Any) -> None:
     check(step["kernel_in_step"], "no tpu_custom_call in the backbone step")
     check(step["rel_err"] <= STEP_REL_TOL,
           f"backbone step off the reference by {step['rel_err']:.3e}")
-    attn = attention_check(cfg, jax.numpy.float32)
-    log(f"flash kernel alone, {cfg.tokens} tokens x {cfg.n_heads} heads x "
-        f"{cfg.head_dim}, f32 inputs, vs reference attention at highest "
-        f"precision: rel L2 err {attn:.3e} (tolerance {ATTN_REL_TOL:g}: "
-        f"above f32 summation noise, below bf16 matmul passes and "
-        f"unmasked padded K rows)")
-    check(attn <= ATTN_REL_TOL, f"flash kernel off the reference by "
-          f"{attn:.3e}")
+    # the backbone's head_dim (64) takes the row sum in the P.V pass's
+    # spare lanes, 128 (FLUX's) on the VPU: check both of the kernel's paths
+    for head_dim in (cfg.head_dim, 128):
+        attn = attention_check(cfg, jax.numpy.float32, head_dim)
+        log(f"flash kernel alone, {cfg.tokens} tokens x {cfg.n_heads} "
+            f"heads x {head_dim}, f32 inputs, vs reference attention at "
+            f"highest precision: rel L2 err {attn:.3e} (tolerance "
+            f"{ATTN_REL_TOL:g}: above f32 summation noise, below bf16 "
+            f"matmul passes and unmasked padded K rows)")
+        check(attn <= ATTN_REL_TOL, f"flash kernel at head_dim {head_dim} "
+              f"off the reference by {attn:.3e}")
 
 
 # ---------------------------------------------------------- four chips

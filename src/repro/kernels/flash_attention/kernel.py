@@ -6,7 +6,7 @@ TPU-native design notes (HARDWARE ADAPTATION):
   tile stays VMEM-resident across the whole K/V sweep; K/V stream through
   in ``(block_k, head_dim)`` tiles.
 * Tile sizes come from the input's shapes (``pick_block``).  Each grid
-  step pays a fixed cost (pipeline bookkeeping, the K/V DMAs, the m/l
+  step pays a fixed cost (pipeline bookkeeping, the K/V DMAs, the
   scratch round trips) and each query tile re-reads all of K and V, so
   tiles are as large as VMEM allows.  Per sequence dimension the cap is
   the largest multiple of 128 up to ``MAX_BLOCK`` whose q, k or v tile
@@ -36,7 +36,23 @@ TPU-native design notes (HARDWARE ADAPTATION):
   so K is never transposed.  Both matmuls take f32 operands: at the
   default precision Mosaic feeds the MXU one bf16 pass, and under
   ``jax.default_matmul_precision("highest")`` it honours f32 (it refuses
-  bf16 operands there).  m, l, the accumulator and exp are f32.
+  bf16 operands there).  m, exp and the accumulator are f32.
+* Where the softmax row sum ``l`` lives depends on ``head_dim``.  The MXU
+  is 128 lanes wide, so a P.V pass at a head_dim that is no multiple of
+  128 leaves output lanes idle.  There V is widened in VMEM (never in
+  HBM) to the next multiple of 128 above ``head_dim``: lane ``head_dim``
+  holds 1.0 and the lanes past it 0, so lane ``head_dim`` of the f32
+  accumulator is ``l``, rescaled by the accumulator's own multiply, at
+  no extra MXU pass.  That replaces an f32 add per score and a
+  cross-lane reduction per row on the VPU.  ``l`` is then the f32 sum of
+  the same probabilities the numerator weighs: bf16-rounded at the
+  default precision, exact f32 under "highest".  Where ``head_dim`` is a
+  multiple of 128 (FLUX's 128, the stand-in encoder's 1024) a ones
+  column would cost a second MXU weight tile per 128 keys, so ``l`` is
+  an f32 VPU sum kept in a ``(block_q, 1)`` scratch.  The
+  ``flash_attention_kernels_built`` counter of the default registry
+  counts the kernels built on each path (``rowsum="mxu_lanes"`` or
+  ``"vpu"``).
 """
 
 from __future__ import annotations
@@ -50,6 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.telemetry import default_registry
+
 NEG_INF = -1e30
 LANES = 128
 MAX_BLOCK = 1024
@@ -58,6 +76,11 @@ MAX_BLOCK = 1024
 TILE_BYTES = 256 * 1024
 # contract the last dimension of both operands: [bq, d] x [bk, d] -> [bq, bk]
 _NT = (((1,), (1,)), ((), ()))
+# kernel instances built (traced), by where the softmax row sum is taken
+_KERNELS_BUILT = default_registry().counter(
+    "flash_attention_kernels_built",
+    "flash-attention kernel instances built, by where the row sum is taken",
+    labelnames=("rowsum",))
 
 
 def pick_block(seq: int, head_dim: int, itemsize: int) -> int:
@@ -72,11 +95,15 @@ def pick_block(seq: int, head_dim: int, itemsize: int) -> int:
 
 
 def _attn_kernel(
-    q_ref, k_ref, v_ref, o_ref,
-    m_scratch, l_scratch, acc_scratch,
-    *, scale: float, causal: bool, window: Optional[int],
-    block_q: int, block_k: int, seq_k: int,
+    q_ref, k_ref, v_ref, o_ref, m_scratch, *rest,
+    scale: float, causal: bool, window: Optional[int],
+    block_q: int, block_k: int, seq_k: int, mxu_rowsum: bool,
 ):
+    d = o_ref.shape[-1]
+    if mxu_rowsum:
+        acc_scratch, v_scratch = rest
+    else:
+        l_scratch, acc_scratch = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -84,8 +111,14 @@ def _attn_kernel(
     @pl.when(ki == 0)
     def _init():
         m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
+        if mxu_rowsum:
+            # lane d of the widened V is 1.0, the lanes past it 0; each
+            # step overwrites lanes [0, d) with the tile of V
+            lane = jax.lax.broadcasted_iota(jnp.int32, v_scratch.shape, 1)
+            v_scratch[...] = jnp.where(lane == d, 1.0, 0.0)
+        else:
+            l_scratch[...] = jnp.zeros_like(l_scratch)
 
     def sweep(masked: bool):
         q = q_ref[...].astype(jnp.float32) * scale      # [bq, d]
@@ -118,8 +151,13 @@ def _attn_kernel(
             # contribute 0, not exp(NEG_INF - NEG_INF) = 1
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)                 # [bq, 1]
-        l_scratch[...] = alpha * l_scratch[...] + jnp.sum(p, axis=-1,
-                                                          keepdims=True)
+        if mxu_rowsum:
+            # lane d of p @ [v | 1 | 0] is the row sum of p
+            v_scratch[:, :d] = v
+            v = v_scratch[...]                          # [bk, dp]
+        else:
+            l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
+                p, axis=-1, keepdims=True)
         acc_scratch[...] = acc_scratch[...] * alpha + p @ v
         m_scratch[...] = m_new
 
@@ -133,9 +171,10 @@ def _attn_kernel(
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_scratch[...]
+        acc = acc_scratch[...]
+        l = acc[:, d:d + 1] if mxu_rowsum else l_scratch[...]
         l = jnp.where(l == 0.0, 1.0, l)                 # fully-masked rows
-        o_ref[...] = (acc_scratch[...] / l).astype(o_ref.dtype)
+        o_ref[...] = (acc[:, :d] / l).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -162,9 +201,20 @@ def flash_attention(
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
 
+    # the row sum rides the P.V matmul where V leaves idle MXU lanes
+    mxu_rowsum = d % LANES != 0
+    _KERNELS_BUILT.labels(rowsum="mxu_lanes" if mxu_rowsum else "vpu").inc()
+    if mxu_rowsum:
+        dp = pl.cdiv(d + 1, LANES) * LANES
+        scratch = [pltpu.VMEM((block_q, dp), jnp.float32),
+                   pltpu.VMEM((block_k, dp), jnp.float32)]
+    else:
+        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
+                   pltpu.VMEM((block_q, d), jnp.float32)]
+
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, seq_k=sk,
+        block_q=block_q, block_k=block_k, seq_k=sk, mxu_rowsum=mxu_rowsum,
     )
     return pl.pallas_call(
         kernel,
@@ -176,10 +226,6 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32), *scratch],
         interpret=interpret,
     )(q, k, v)
