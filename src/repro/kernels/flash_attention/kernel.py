@@ -39,11 +39,11 @@ TPU-native design notes (HARDWARE ADAPTATION):
   bf16 operands there).  m, exp and the accumulator are f32.
 * Where the softmax row sum ``l`` lives depends on ``head_dim``.  The MXU
   is 128 lanes wide, so a P.V pass at a head_dim that is no multiple of
-  128 leaves output lanes idle.  There V is widened in VMEM (never in
-  HBM) to the next multiple of 128 above ``head_dim``: lane ``head_dim``
-  holds 1.0 and the lanes past it 0, so lane ``head_dim`` of the f32
-  accumulator is ``l``, rescaled by the accumulator's own multiply, at
-  no extra MXU pass.  That replaces an f32 add per score and a
+  128 leaves output lanes idle.  There each V tile is widened in the
+  kernel (never in HBM) to the next multiple of 128 above ``head_dim``,
+  lane ``head_dim`` holding 1.0 and the lanes past it 0, and the f32
+  accumulator with it, so its lane ``head_dim`` is ``l``, rescaled by
+  the accumulator's own multiply, at no extra MXU pass.  That replaces an f32 add per score and a
   cross-lane reduction per row on the VPU.  ``l`` is then the f32 sum of
   the same probabilities the numerator weighs: bf16-rounded at the
   default precision, exact f32 under "highest".  Where ``head_dim`` is a
@@ -95,15 +95,17 @@ def pick_block(seq: int, head_dim: int, itemsize: int) -> int:
 
 
 def _attn_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scratch, *rest,
+    q_ref, k_ref, v_ref, o_ref, m_scratch, acc_scratch, *l_scratch,
     scale: float, causal: bool, window: Optional[int],
-    block_q: int, block_k: int, seq_k: int, mxu_rowsum: bool,
+    block_q: int, block_k: int, seq_k: int,
 ):
     d = o_ref.shape[-1]
-    if mxu_rowsum:
-        acc_scratch, v_scratch = rest
-    else:
-        l_scratch, acc_scratch = rest
+    dp = acc_scratch.shape[-1]
+    # a widened accumulator carries the row sum in lane d; else l has a
+    # scratch of its own
+    mxu_rowsum = dp > d
+    if not mxu_rowsum:
+        (l_scratch,) = l_scratch
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -112,12 +114,7 @@ def _attn_kernel(
     def _init():
         m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
-        if mxu_rowsum:
-            # lane d of the widened V is 1.0, the lanes past it 0; each
-            # step overwrites lanes [0, d) with the tile of V
-            lane = jax.lax.broadcasted_iota(jnp.int32, v_scratch.shape, 1)
-            v_scratch[...] = jnp.where(lane == d, 1.0, 0.0)
-        else:
+        if not mxu_rowsum:
             l_scratch[...] = jnp.zeros_like(l_scratch)
 
     def sweep(masked: bool):
@@ -153,8 +150,9 @@ def _attn_kernel(
         alpha = jnp.exp(m_prev - m_new)                 # [bq, 1]
         if mxu_rowsum:
             # lane d of p @ [v | 1 | 0] is the row sum of p
-            v_scratch[:, :d] = v
-            v = v_scratch[...]                          # [bk, dp]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (block_k, dp - d), 1)
+            v = jnp.concatenate([v, jnp.where(lane == 0, 1.0, 0.0)],
+                                axis=1)                 # [bk, dp]
         else:
             l_scratch[...] = alpha * l_scratch[...] + jnp.sum(
                 p, axis=-1, keepdims=True)
@@ -204,17 +202,15 @@ def flash_attention(
     # the row sum rides the P.V matmul where V leaves idle MXU lanes
     mxu_rowsum = d % LANES != 0
     _KERNELS_BUILT.labels(rowsum="mxu_lanes" if mxu_rowsum else "vpu").inc()
-    if mxu_rowsum:
-        dp = pl.cdiv(d + 1, LANES) * LANES
-        scratch = [pltpu.VMEM((block_q, dp), jnp.float32),
-                   pltpu.VMEM((block_k, dp), jnp.float32)]
-    else:
-        scratch = [pltpu.VMEM((block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_q, d), jnp.float32)]
+    dp = pl.cdiv(d + 1, LANES) * LANES if mxu_rowsum else d
+    scratch = [pltpu.VMEM((block_q, 1), jnp.float32),       # m
+               pltpu.VMEM((block_q, dp), jnp.float32)]      # acc
+    if not mxu_rowsum:
+        scratch.append(pltpu.VMEM((block_q, 1), jnp.float32))  # l
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, seq_k=sk, mxu_rowsum=mxu_rowsum,
+        block_q=block_q, block_k=block_k, seq_k=sk,
     )
     return pl.pallas_call(
         kernel,
@@ -226,6 +222,6 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32), *scratch],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v)
