@@ -43,8 +43,10 @@ TPU-native design notes (HARDWARE ADAPTATION):
   kernel (never in HBM) to the next multiple of 128 above ``head_dim``,
   lane ``head_dim`` holding 1.0 and the lanes past it 0, and the f32
   accumulator with it, so its lane ``head_dim`` is ``l``, rescaled by
-  the accumulator's own multiply, at no extra MXU pass.  That replaces an f32 add per score and a
-  cross-lane reduction per row on the VPU.  ``l`` is then the f32 sum of
+  the accumulator's own multiply, at no extra MXU pass.  That replaces
+  an f32 add per score and a cross-lane reduction per row on the VPU
+  (the concatenate is in registers: a VMEM scratch for the wide V
+  scheduled longer on the masked tile).  ``l`` is then the f32 sum of
   the same probabilities the numerator weighs: bf16-rounded at the
   default precision, exact f32 under "highest".  Where ``head_dim`` is a
   multiple of 128 (FLUX's 128, the stand-in encoder's 1024) a ones
